@@ -252,7 +252,9 @@ def fast_eco_minimal():
 
 
 def _scaling(graphs, shard_counts, k):
-    """Runs the distributed engine in subprocesses with N host devices."""
+    """Runs the distributed engine in subprocesses with N virtual CPU
+    devices (pinned to the CPU: a child must never claim the chip its
+    parent may hold)."""
     import os
     import subprocess
 
@@ -273,10 +275,11 @@ t0 = time.time()
 clus = lp_cluster_distributed(plan, U=max(1.0, L/64), iters=3, seed=0)
 t_lp = time.time() - t0
 gf = float(plan.sg.n_ghost.sum()) / g.n
-print(f"RESULT,{gname},{P},{{g.n}},{{g.m}},{{t_plan:.2f}},{{t_lp:.2f}},{{gf:.3f}}")
+print(f"RESULT,{gname},{P},cpu,{{g.n}},{{g.m}},{{t_plan:.2f}},{{t_lp:.2f}},{{gf:.3f}}")
 """
             env = dict(os.environ)
             env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={P}"
+            env["JAX_PLATFORMS"] = "cpu"
             env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
             r = subprocess.run([sys.executable, "-c", code],
                                capture_output=True, text=True, timeout=900,
@@ -287,14 +290,14 @@ print(f"RESULT,{gname},{P},{{g.n}},{{g.m}},{{t_plan:.2f}},{{t_lp:.2f}},{{gf:.3f}
                     rows.append(line)
                     got = True
             if not got:
-                rows.append(f"RESULT,{gname},{P},ERROR,,,,{r.stderr[-200:]!r}")
+                rows.append(f"RESULT,{gname},{P},cpu,ERROR,,,,{r.stderr[-200:]!r}")
     return rows
 
 
 def weak_scaling():
     print("# Weak scaling (Fig. 5 stand-in): graph grows with shard count, "
           "k=16; LP time should grow ~linearly with graph (flat per edge).")
-    print("graph,shards,n,m,plan_s,lp_s,ghost_frac")
+    print("graph,shards,platform,n,m,plan_s,lp_s,ghost_frac")
     rows = []
     for P, sc_rgg, sc_mesh in [(1, 13, 90), (2, 14, 128), (4, 15, 181),
                                (8, 16, 256)]:
@@ -306,7 +309,7 @@ def weak_scaling():
 
 def strong_scaling():
     print("# Strong scaling (Fig. 6 stand-in): fixed graphs, shards 1..8, k=2")
-    print("graph,shards,n,m,plan_s,lp_s,ghost_frac")
+    print("graph,shards,platform,n,m,plan_s,lp_s,ghost_frac")
     rows = _scaling([("rgg", 14), ("mesh", 181)], [1, 2, 4, 8], 2)
     for r in rows:
         print(r.replace("RESULT,", ""))
@@ -779,26 +782,12 @@ def evo_hot():
 
 def _churn_stream(g, sess, nb, rng):
     """~nb random adds + nb removals of surviving original edges per batch
-    (the PR 4 churn model, parameterized — shared by dynamic_hot and
-    obs_overhead so both time the same steady state)."""
-    from repro.dynamic import GraphUpdate
+    (the PR 4 churn model, ``repro.dynamic.churn_updates`` — shared by
+    dynamic_hot and obs_overhead so both time the same steady state)."""
+    from repro.dynamic import churn_updates
 
-    src0 = g.arc_sources()
-    # canonical (src < dst) arcs only: each edge sampled once
-    removed = src0 >= g.indices
-
-    def one_batch():
-        au = rng.integers(0, sess.n, nb)
-        av = (au + 1 + rng.integers(0, sess.n - 1, nb)) % sess.n
-        cand = rng.permutation(np.flatnonzero(~removed))[:nb]
-        removed[cand] = True
-        ru, rv = src0[cand], g.indices[cand]
-        return sess.update(
-            GraphUpdate.add_edges(au, av).merged(
-                GraphUpdate.remove_edges(ru, rv))
-        )
-
-    return one_batch
+    stream = churn_updates(g, nb, rng)
+    return lambda: sess.update(next(stream))
 
 
 def dynamic_hot():
@@ -1853,6 +1842,9 @@ def main() -> None:
     if only and only not in TABLES:
         sys.exit(f"error: unknown table {only!r}; available: "
                  + ", ".join(TABLES))
+    from repro import compile_cache
+
+    compile_cache.enable()
     # parse any existing results file up front so a corrupt file fails the
     # run before hours of benchmarking, not after
     merged = {}

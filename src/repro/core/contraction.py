@@ -43,7 +43,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..graph.csr import GraphNP
+from ..graph.csr import GraphNP, sort_by_keys, sort_values
 
 __all__ = [
     "CoarseMap",
@@ -214,7 +214,7 @@ def contract_device(src, dst, ew, nw, labels, n, m, *, wbits: int = 0):
     # sort of the labels, dense ranks via cumsum, and C[v] recovered by
     # binary search for the first occurrence — no payload sort needed.
     lab = jnp.where(node_valid, labels, sent)
-    sl = jnp.sort(lab)
+    sl = sort_values(lab)
     newrun_n = jnp.concatenate(
         [sl[:1] < sent, (sl[1:] != sl[:-1]) & (sl[1:] < sent)]
     )
@@ -246,14 +246,14 @@ def contract_device(src, dst, ew, nw, labels, n, m, *, wbits: int = 0):
         key = jnp.where(
             ok, (pair << wbits) | ew.astype(jnp.uint32), big
         )
-        ks = jnp.sort(key)
+        ks = sort_values(key)
         oks = ks < big
         khi = ks >> wbits
         first = jnp.concatenate([oks[:1], oks[1:] & (khi[1:] != khi[:-1])])
         # compaction by sorting the masked iota: run-first positions are
         # increasing, so a second value-only sort IS the compaction (cheaper
         # than a searchsorted over Mb queries on every backend measured)
-        firstpos = jnp.sort(jnp.where(first, iota_m, jnp.int32(Mb)))
+        firstpos = sort_values(jnp.where(first, iota_m, jnp.int32(Mb)))
         fp = jnp.minimum(firstpos, Mb - 1)
         m_c = jnp.sum(first).astype(jnp.int32)
         arc_ok = iota_m < m_c
@@ -274,10 +274,10 @@ def contract_device(src, dst, ew, nw, labels, n, m, *, wbits: int = 0):
         # the f32 segment sums
         big = jnp.int32(2**31 - 1)
         key = jnp.where(ok, cu * jnp.int32(Nb) + cv, big)
-        ks = jnp.sort(key)
+        ks = sort_values(key)
         oks = ks < big
         first = jnp.concatenate([oks[:1], oks[1:] & (ks[1:] != ks[:-1])])
-        firstpos = jnp.sort(jnp.where(first, iota_m, jnp.int32(Mb)))
+        firstpos = sort_values(jnp.where(first, iota_m, jnp.int32(Mb)))
         fp = jnp.minimum(firstpos, Mb - 1)
         m_c = jnp.sum(first).astype(jnp.int32)
         arc_ok = iota_m < m_c
@@ -293,8 +293,8 @@ def contract_device(src, dst, ew, nw, labels, n, m, *, wbits: int = 0):
     else:
         # > 46k-node levels: two-pass lexicographic payload sort (rare at
         # this repo's scales; correct for any size without int64)
-        aorder = jnp.lexsort(
-            (jnp.where(ok, cv, sent), jnp.where(ok, cu, sent))
+        aorder = sort_by_keys(
+            jnp.where(ok, cu, sent), jnp.where(ok, cv, sent)
         )
         oks = ok[aorder]
         cu_s = jnp.where(oks, cu[aorder], sent)
@@ -305,7 +305,7 @@ def contract_device(src, dst, ew, nw, labels, n, m, *, wbits: int = 0):
                 oks[1:] & ((cu_s[1:] != cu_s[:-1]) | (cv_s[1:] != cv_s[:-1])),
             ]
         )
-        firstpos = jnp.sort(jnp.where(first, iota_m, jnp.int32(Mb)))
+        firstpos = sort_values(jnp.where(first, iota_m, jnp.int32(Mb)))
         fp = jnp.minimum(firstpos, Mb - 1)
         m_c = jnp.sum(first).astype(jnp.int32)
         arc_ok = iota_m < m_c

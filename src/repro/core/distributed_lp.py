@@ -39,7 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map as shard_map_compat
 from ..graph.csr import GraphNP
 from ..graph.packing import ShardedGraph, pack_chunks, shard_graph
 
@@ -380,11 +379,12 @@ def _run_distributed(
         )
         return out[0][None], out[1][None], out[2]
 
-    shmapped = shard_map_compat(
+    shmapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec,) * 15 + (P(),),
         out_specs=(spec, spec, P()),
+        check_vma=False,
     )
     key = jax.random.PRNGKey(seed)
     out_ll, out_lg, moves = jax.jit(shmapped)(
@@ -464,10 +464,11 @@ def contract_distributed(plan: DistLPPlan, labels_global: np.ndarray):
         )
         return cu2[None], cv2[None], w2[None], v2[None]
 
-    out = jax.jit(shard_map_compat(
+    out = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec,) * 6,
         out_specs=(spec,) * 4,
+        check_vma=False,
     ))(
         jnp.asarray(sg.indptr), jnp.asarray(sg.indices), jnp.asarray(sg.ew),
         jnp.asarray(sg.m_local), jnp.asarray(cl), jnp.asarray(cg),

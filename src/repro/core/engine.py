@@ -67,12 +67,14 @@ from ..graph.packing import (
     plan_ell_rows,
     plan_region_pack,
 )
+from ..kernels.lp_score.lp_score import default_interpret
 from ..obs import MetricsRegistry, RegistryBackedStats
 from ..obs import span as _obs_span
 from ..obs import watchdog as _obs_watchdog
 from ..obs.memory import account as _mem_account
 from .contraction import CoarseMap, contract_device, packed_key_wbits
 from .label_propagation import _lp_sweep, make_order
+from .metrics import cut_from_arcs_jnp
 
 __all__ = ["LPEngine", "EngineStats"]
 
@@ -109,6 +111,8 @@ class _Arena:
     src: jax.Array          # (>= m,) int32 — arc sources (padding carries w 0)
     dst: jax.Array          # (>= m,) int32
     ew: jax.Array           # (>= m,) f32
+    integral: bool          # integral arc weights totalling < 2**31: cuts
+                            # sum exactly in int32
 
 
 @dataclass
@@ -226,7 +230,7 @@ class LPEngine:
         self.seed = int(seed)
         self.use_pallas = bool(use_pallas)
         self.interpret = (
-            (jax.default_backend() != "tpu") if interpret is None else bool(interpret)
+            default_interpret() if interpret is None else bool(interpret)
         )
         self.stats = EngineStats(registry)
         self._packs: Dict[Tuple[int, str], _DevicePack] = {}
@@ -240,8 +244,7 @@ class LPEngine:
         self._compile_keys = set()
         self._gather_keys = set()
         self._dense_keys = set()
-        self._exact_weights: Optional[bool] = None  # lazily scanned from g0
-        self._g0 = g0
+        self._exact: Dict[int, tuple] = {}  # id(g) -> (g, GA weights exact)
         self._shard_steps: Dict[tuple, object] = {}
 
     @property
@@ -281,6 +284,8 @@ class LPEngine:
             ar = _Arena(
                 graph=g, nw_arena=nw_arena, cluster_w=cw,
                 src=g.src, dst=g.indices, ew=g.ew,
+                integral=(g.m == 0 or g.ew_integral)
+                and float(jnp.sum(g.ew)) < 2**31,
             )
         else:
             nw = np.zeros(self.A, np.float32)
@@ -294,6 +299,8 @@ class LPEngine:
                 src=jnp.asarray(g.arc_sources(), dtype=jnp.int32),
                 dst=jnp.asarray(g.indices, dtype=jnp.int32),
                 ew=jnp.asarray(g.ew, dtype=jnp.float32),
+                integral=bool(np.all(g.ew == np.round(g.ew)))
+                and float(g.ew.sum()) < 2**31,
             )
             self.stats.h2d_bytes += self.A * 8 + g.m * 12
         # GraphDev aliases (src/dst/ew) are already owned by base_csr —
@@ -535,6 +542,7 @@ class LPEngine:
         self._cin = {k: v for k, v in self._cin.items() if k in keep_ids}
         self._degs = {k: v for k, v in self._degs.items() if k in keep_ids}
         self._indptrs = {k: v for k, v in self._indptrs.items() if k in keep_ids}
+        self._exact = {k: v for k, v in self._exact.items() if k in keep_ids}
 
     # ------------------------------------------------------------------ sweeps
 
@@ -782,8 +790,9 @@ class LPEngine:
         def cut_now(labels_: jax.Array) -> float:
             if adjacency is None:
                 return self.cut(g, labels_)
-            diff = labels_[a_src] != labels_[a_dst]
-            return float(jnp.sum(jnp.where(diff, a_ew, 0.0)) / 2.0)
+            return float(cut_from_arcs_jnp(
+                labels_, a_src, a_dst, a_ew, integral=ar.integral
+            ))
 
         lab = self.to_arena(labels, n, fill=k)
         t_ids = np.unique(np.asarray(touched, dtype=np.int64))
@@ -931,31 +940,33 @@ class LPEngine:
         self._degs[id(g)] = arr
         return arr
 
-    def _weights_exact(self) -> bool:
-        """Integral node/edge weights with f32-exact sums (scanned once from
-        the finest graph; contraction only sums, so every coarse level
-        inherits the property) — the precondition for bit-exact int32
-        fitness keys and order-independent f32 scatter sums."""
-        if self._exact_weights is None:
-            g = self._g0
-            if isinstance(g, GraphDev):
-                # device-resident finest graph (the dynamic session's
-                # escalation path): integrality of ew is tracked metadata,
-                # nw is scanned on device — padding is 0, hence inert
-                self._exact_weights = bool(
-                    (g.m == 0 or g.ew_integral)
-                    and bool(jnp.all(g.nw == jnp.round(g.nw)))
-                    and float(jnp.sum(g.ew)) < 2**24
-                    and float(jnp.sum(g.nw)) < 2**24
-                )
-            else:
-                self._exact_weights = bool(
-                    (g.m == 0 or np.all(g.ew == np.round(g.ew)))
-                    and np.all(g.nw == np.round(g.nw))
-                    and float(g.ew.sum()) < 2**24
-                    and float(g.nw.sum()) < 2**24
-                )
-        return self._exact_weights
+    def _weights_exact(self, g: AnyGraph) -> bool:
+        """Whether the GA's scatter sums are exact in any order on ``g``:
+        integral weights, total node weight (block weights) and the largest
+        weighted degree (per-node block connections) below 2**24 in f32, and
+        an arc total the int32 cut of the fitness key can hold.  Checked on
+        the graph the GA runs on: contraction keeps the node total, shrinks
+        the arc total and grows weighted degrees."""
+        hit = self._exact.get(id(g))
+        if hit is not None and hit[0] is g:
+            return hit[1]
+        ar = self._arena(g)
+        if isinstance(g, GraphDev):
+            nw_ok = (bool(jnp.all(g.nw == jnp.round(g.nw)))
+                     and float(jnp.sum(g.nw)) < 2**24)
+            wdeg = 0.0
+            if ar.integral and g.m:
+                cs = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                      jnp.cumsum(g.ew.astype(jnp.int32))])
+                wdeg = float(jnp.max(cs[g.indptr[1:]] - cs[g.indptr[:-1]]))
+        else:
+            nw_ok = (bool(np.all(g.nw == np.round(g.nw)))
+                     and float(g.nw.sum()) < 2**24)
+            cs = np.concatenate([[0.0], np.cumsum(g.ew, dtype=np.float64)])
+            wdeg = float(np.max(cs[g.indptr[1:]] - cs[g.indptr[:-1]])) if g.m else 0.0
+        ok = bool(ar.integral and nw_ok and wdeg < 2**24)
+        self._exact[id(g)] = (g, ok)
+        return ok
 
     def can_evolve_device(self, g: AnyGraph, k: int, islands: int,
                           pop: int) -> bool:
@@ -970,12 +981,12 @@ class LPEngine:
         Sb = _pow2(max(islands * pop, 1))
         if Sb * Ab * Kb * 4 > 2**28:
             return False
-        return self._weights_exact()
+        return self._weights_exact(g)
 
     def _evo_arrays(self, g: AnyGraph):
-        """(pack, arc arrays, nw, deg, Ab) for one evolution run; the pack is
-        the cached "random" pack (shared with refine sweeps), so the graph
-        uploads once per run, not once per individual."""
+        """(pack, arena, Ab) for one evolution run; the pack is the cached
+        "random" pack (shared with refine sweeps), so the graph uploads once
+        per run, not once per individual."""
         dp = self._pack(g, "random")
         ar = self._arena(g)
         Ab = _pow2(g.n + 1)
@@ -1327,10 +1338,12 @@ class LPEngine:
         return out
 
     def cut(self, g: AnyGraph, labels: jax.Array) -> float:
-        """Edge cut of arena labels, evaluated on device (one scalar sync)."""
+        """Edge cut of arena labels, evaluated on device (one scalar sync);
+        exact for integral weights at any size."""
         ar = self._arena(g)
-        diff = labels[ar.src] != labels[ar.dst]
-        return float(jnp.sum(jnp.where(diff, ar.ew, 0.0)) / 2.0)
+        return float(cut_from_arcs_jnp(
+            labels, ar.src, ar.dst, ar.ew, integral=ar.integral
+        ))
 
     def block_weights(self, g: AnyGraph, labels: jax.Array, k: int) -> np.ndarray:
         ar = self._arena(g)

@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..graph.csr import sort_by_keys, sort_values
 from .evolutionary import (
     CELL_ROUNDS,
     COMBINE_PROB,
@@ -97,11 +98,11 @@ def _bw_dev(lab, nw, k, Kb):
 def _evaluate(lab, src, dst, ew, nw, k, Kb, Lmax):
     """int32 fitness key: cut + INFEAS_PENALTY if infeasible (oracle twin)."""
     kio = jnp.arange(Kb, dtype=jnp.int32)
-    cut = cut_from_arcs_jnp(lab, src, dst, ew)
+    cut = cut_from_arcs_jnp(lab, src, dst, ew, integral=True)
     bw, _ = _bw_dev(lab, nw, k, Kb)
     bwmax = jnp.max(jnp.where(kio < k, bw, -jnp.inf))
     feas = bwmax <= Lmax + 1e-6
-    return cut.astype(jnp.int32) + jnp.where(feas, 0, INFEAS_PENALTY)
+    return cut + jnp.where(feas, 0, INFEAS_PENALTY)
 
 
 def _greedy_one(s_idx, src, dst, ew, nw, deg_f, n, k, Kb, Lmax, seed, rounds):
@@ -115,7 +116,7 @@ def _greedy_one(s_idx, src, dst, ew, nw, deg_f, n, k, Kb, Lmax, seed, rounds):
     kio = jnp.arange(Kb, dtype=jnp.int32)
     unit = _hash_unit(_hash_base(seed, jnp.int32(0), TAG_SEEDKEY), iota, s_idx)
     skey = jnp.where(iota < n, unit * (deg_f + 1.0), -jnp.inf)
-    order = jnp.argsort(-skey)
+    order = sort_by_keys(-skey)
     rank = jnp.zeros((Ab,), jnp.int32).at[order].set(iota)
     lab0 = jnp.where((rank < k) & (iota < n), rank, jnp.int32(-1))
 
@@ -243,7 +244,7 @@ def _combine_init(src, dst, ew, nw, lab1, lab2, lab_better, i_ctx, gen, n, k,
     iota = jnp.arange(Ab, dtype=jnp.int32)
     kio = jnp.arange(Kb, dtype=jnp.int32)
     ov = jnp.where(iota < n, lab1 * k + lab2, jnp.int32(_IMAX))
-    sl = jnp.sort(ov)
+    sl = sort_values(ov)
     newrun = jnp.concatenate(
         [sl[:1] < _IMAX, (sl[1:] != sl[:-1]) & (sl[1:] < _IMAX)]
     )
@@ -519,8 +520,6 @@ def make_generation_sharded(mesh, refine_iters: int, Kb: int, Ib: int):
     column, so results are bit-identical to the single-device step."""
     from jax.sharding import PartitionSpec as PS
 
-    from ..compat import shard_map
-
     def step(pack_and_state):
         (nodes, node_valid, edge_dst, edge_w, edge_src_slot, edge_valid,
          labs, keys, src, dst, ew, nw, Lmax, seed, gen, island_offset,
@@ -541,8 +540,9 @@ def make_generation_sharded(mesh, refine_iters: int, Kb: int, Ib: int):
         rep, rep, rep, PS("island"),                    # Lmax, seed, gen, off
         rep, rep, rep, rep, rep,                        # I_loc, P, n, k, chunks
     )
-    sharded = shard_map(
-        lambda *a: step(a), mesh,
+    sharded = jax.shard_map(
+        lambda *a: step(a), mesh=mesh,
         in_specs=spec_in, out_specs=(PS("island"), PS("island")),
+        check_vma=False,
     )
     return jax.jit(sharded)

@@ -311,13 +311,11 @@ def _evaluate_np(inp: EvoInputs, lab, k: int, Kb: int, Lmax) -> tuple:
     """int32 fitness key (feasibility-first, then cut; exact for integral
     weights), plus (cut, feasible)."""
     diff = lab[inp.src] != lab[inp.dst]
-    cut = np.where(diff, inp.ew, np.float32(0.0)).astype(np.float32).sum(
-        dtype=np.float32
-    ) / np.float32(2.0)
+    cut = int(np.where(diff, inp.ew, 0.0).astype(np.int64).sum()) // 2
     _, bwx = _bw_np(lab, inp.nw, k, Kb)
     bwmax = np.max(np.where(np.arange(Kb) < k, bwx, np.float32(-np.inf)))
     feas = bool(bwmax <= np.float32(Lmax) + np.float32(1e-6))
-    key = int(np.int32(cut)) + (0 if feas else INFEAS_PENALTY)
+    key = cut + (0 if feas else INFEAS_PENALTY)
     return key, float(cut), feas
 
 

@@ -60,7 +60,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..graph.csr import GraphNP
+from ..graph.csr import GraphNP, sort_by_keys
 from ..graph.packing import ChunkPack, pack_chunks
 
 __all__ = [
@@ -186,9 +186,9 @@ def _lp_sweep(
             # always <= num_labels < A, so the key is collision-free; the
             # int32 fast path is valid whenever N * A fits in 31 bits.
             if N * A < 2**31:
-                perm_e = jnp.argsort(slot * jnp.int32(A) + cand)
+                perm_e = sort_by_keys(slot * jnp.int32(A) + cand)
             else:
-                perm_e = jnp.lexsort((cand, slot))
+                perm_e = sort_by_keys(slot, cand)
             s_slot = slot[perm_e]
             s_lbl = cand[perm_e]
             s_w = wv[perm_e]

@@ -21,12 +21,15 @@ __all__ = [
 ]
 
 
-def cut_from_arcs_jnp(labels, src, dst, ew):
+def cut_from_arcs_jnp(labels, src, dst, ew, integral: bool = False):
     """Edge cut from flat arc arrays on device (one individual; ``vmap`` the
     labels axis for a population batch).  Trailing zero-weight arc padding is
-    inert; for integral weights the f32 sum is exact in any order — the
-    batched evolutionary fitness relies on that exactness."""
+    inert.  ``integral=True`` promises integral weights with an arc total
+    below 2**31 and sums in int32 -- exact in any order, where an f32 sum
+    stops being exact once the cut passes 2**24 -- returning an int32."""
     diff = labels[src] != labels[dst]
+    if integral:
+        return jnp.sum(jnp.where(diff, ew, 0.0).astype(jnp.int32)) // 2
     return jnp.sum(jnp.where(diff, ew, 0.0)) / 2.0
 
 

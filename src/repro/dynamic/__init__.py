@@ -22,7 +22,12 @@ Three layers (ISSUE 4):
 
 from .group import GroupStats, SessionGroup
 from .session import PartitionSession, SessionConfig, UpdateResult
-from .store import DynamicGraphStore, GraphUpdate, UpdateValidationError
+from .store import (
+    DynamicGraphStore,
+    GraphUpdate,
+    UpdateValidationError,
+    churn_updates,
+)
 
 __all__ = [
     "DynamicGraphStore",
@@ -33,4 +38,5 @@ __all__ = [
     "SessionGroup",
     "UpdateResult",
     "UpdateValidationError",
+    "churn_updates",
 ]

@@ -43,7 +43,15 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..graph.csr import GraphDev, GraphNP, arc_bucket, pow2, to_device_csr
+from ..graph.csr import (
+    GraphDev,
+    GraphNP,
+    arc_bucket,
+    pow2,
+    sort_by_keys,
+    sort_values,
+    to_device_csr,
+)
 from ..obs import MetricsRegistry, RegistryBackedStats
 from ..obs import span as _obs_span
 from ..obs import watchdog as _obs_watchdog
@@ -54,6 +62,7 @@ __all__ = [
     "GraphUpdate",
     "StoreStats",
     "UpdateValidationError",
+    "churn_updates",
     "merge_overlay_device",
     "overlay_view_device",
     "vacuum_device",
@@ -305,6 +314,23 @@ class GraphUpdate:
         return (first[live] // n, first[live] % n, net[live])
 
 
+def churn_updates(g: GraphNP, nb: int, rng: np.random.Generator):
+    """Endless edge-churn stream over ``g``'s fixed node set: each batch adds
+    ``nb`` random edges and removes ``nb`` edges of ``g`` not removed by an
+    earlier batch (each original edge is sampled once, from its canonical
+    ``src < dst`` arc)."""
+    src0 = g.arc_sources()
+    removed = src0 >= g.indices
+    while True:
+        au = rng.integers(0, g.n, nb)
+        av = (au + 1 + rng.integers(0, g.n - 1, nb)) % g.n
+        cand = rng.permutation(np.flatnonzero(~removed))[:nb]
+        removed[cand] = True
+        yield GraphUpdate.add_edges(au, av).merged(
+            GraphUpdate.remove_edges(src0[cand], g.indices[cand])
+        )
+
+
 class StoreStats(RegistryBackedStats):
     """Counters surfaced through ``PartitionSession.stats()``.
 
@@ -355,13 +381,13 @@ def _merge_body(src, dst, ew, ou, ov, ow, nw, n, m, r):
         # for the integral deltas the store enforces
         big = jnp.int32(2**31 - 1)
         key = jnp.where(valid, u * jnp.int32(Nb) + v, big)
-        ks = jnp.sort(key)
+        ks = sort_values(key)
         oks = ks < big
         first = jnp.concatenate([oks[:1], oks[1:] & (ks[1:] != ks[:-1])])
         run = (jnp.cumsum(first) - 1).astype(jnp.int32)
         pos = jnp.minimum(jnp.searchsorted(ks, key), T - 1)
         run_of = jnp.where(valid, run[pos], T)
-        firstpos = jnp.sort(jnp.where(first, iota, jnp.int32(T)))
+        firstpos = sort_values(jnp.where(first, iota, jnp.int32(T)))
         fp = jnp.minimum(firstpos, T - 1)
         uk = ks[fp]
         ru = (uk // jnp.int32(Nb)).astype(jnp.int32)
@@ -370,7 +396,7 @@ def _merge_body(src, dst, ew, ou, ov, ow, nw, n, m, r):
         # > 46k-node graphs: two-pass payload lexsort (mirrors the
         # contract_device fallback; rare at this repo's scales)
         sent = jnp.int32(Nb)
-        aorder = jnp.lexsort((jnp.where(valid, v, sent), jnp.where(valid, u, sent)))
+        aorder = sort_by_keys(jnp.where(valid, u, sent), jnp.where(valid, v, sent))
         oks = valid[aorder]
         u_s = jnp.where(oks, u[aorder], sent)
         v_s = jnp.where(oks, v[aorder], sent)
@@ -382,7 +408,7 @@ def _merge_body(src, dst, ew, ou, ov, ow, nw, n, m, r):
             jnp.where(oks, run, T)
         )
         run_of = jnp.where(valid, run_of, T)
-        firstpos = jnp.sort(jnp.where(first, iota, jnp.int32(T)))
+        firstpos = sort_values(jnp.where(first, iota, jnp.int32(T)))
         fp = jnp.minimum(firstpos, T - 1)
         ru = u_s[fp]
         rv = v_s[fp]
@@ -393,7 +419,7 @@ def _merge_body(src, dst, ew, ou, ov, ow, nw, n, m, r):
     # drop runs whose merged weight hit zero (removed edges); kept runs stay
     # in (u, v) key order, so a second value-only sort IS the compaction
     keep = (iota < nrun) & (rw > 0.0)
-    kpos = jnp.sort(jnp.where(keep, iota, jnp.int32(T)))
+    kpos = sort_values(jnp.where(keep, iota, jnp.int32(T)))
     kp = jnp.minimum(kpos, T - 1)
     m_new = jnp.sum(keep).astype(jnp.int32)
     arc_ok = iota < m_new
@@ -461,7 +487,7 @@ def _view_body(indptr, src, dst, ew, ou, ov, ow, n, m, r):
     # ---- dedup the overlay: net signed delta per distinct (u, v) ----
     big = jnp.int32(2**31 - 1)
     key = jnp.where(valid_o, ou * jnp.int32(Nb) + ov, big)
-    ks = jnp.sort(key)
+    ks = sort_values(key)
     oks = ks < big
     first = jnp.concatenate([oks[:1], oks[1:] & (ks[1:] != ks[:-1])])
     run = (jnp.cumsum(first) - 1).astype(jnp.int32)
@@ -471,7 +497,7 @@ def _view_body(indptr, src, dst, ew, ou, ov, ow, n, m, r):
     dw = jnp.zeros((Rb,), jnp.float32).at[run_of].add(
         jnp.where(valid_o, ow, 0.0), mode="drop"
     )
-    firstpos = jnp.sort(jnp.where(first, iota_r, jnp.int32(Rb)))
+    firstpos = sort_values(jnp.where(first, iota_r, jnp.int32(Rb)))
     fp = jnp.minimum(firstpos, Rb - 1)
     uk = ks[fp]
     run_live = iota_r < nrun

@@ -1,4 +1,4 @@
-from .lp_score import lp_score_rows
+from .lp_score import default_interpret, lp_score_rows
 from .ops import (
     dense_eligibility,
     dense_round_device,
@@ -10,6 +10,7 @@ from .ops import (
 from .ref import lp_score_rows_ref, node_scores_ref
 
 __all__ = [
+    "default_interpret",
     "lp_score_rows",
     "lp_score_rows_ref",
     "node_scores",

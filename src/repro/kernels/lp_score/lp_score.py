@@ -8,18 +8,18 @@ the strongest connection" — reduces, for labels in [0, k), to
 The paper computes this with per-node hash maps (linear probing), which has
 no sensible TPU mapping.  The TPU-native formulation: adjacency in row-split
 ELL layout (``repro.graph.packing.ell_pack``), neighbour labels pre-gathered
-by XLA, and the kernel accumulating a dense (TILE_R, K) score tile in VMEM
-with VPU compare+select one-hot accumulation, sweeping the ELL width in
-small slices so the (TILE_R, WC, K) broadcast stays inside VMEM.
+by XLA, and the kernel accumulating a dense score tile in VMEM with VPU
+compare+select one-hot accumulation, one ELL column at a time.
 
 Layout & tiling:
-  * rows (TILE_R = 256) on the grid's first axis — each grid step owns a
-    (TILE_R, K) fp32 accumulator in VMEM (256 x 128 x 4 B = 128 KiB);
-  * K padded to a lane multiple (128);
-  * ELL width swept in WC = 8 slices: working set per step is the
-    (TILE_R, WC) label/weight planes (8 KiB each) plus the one-hot
-    broadcast (TILE_R x WC x K x 4 B = 1 MiB) — comfortably inside the
-    ~16 MiB VMEM budget with double buffering.
+  * the kernel works on the transposed problem: ELL columns on sublanes,
+    rows on lanes.  Each grid step owns TILE_R = 256 rows and a
+    (K, TILE_R) fp32 accumulator (128 x 256 x 4 B = 128 KiB);
+  * K padded to a lane multiple (128), so the transposed-back output is
+    lane-dense (R, K);
+  * the ELL width is swept in WC = 8 sublane slabs: every dynamic slice
+    starts at a multiple of 8 sublanes, which Mosaic can prove aligned at
+    any width (lane-axis slices of 8 at a dynamic offset it refuses).
 
 A node of degree d owns ceil(d / W) consecutive rows; the caller
 segment-sums row scores into node scores (XLA), so power-law degrees cannot
@@ -34,27 +34,38 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["lp_score_rows", "TILE_R", "LANE"]
+__all__ = ["lp_score_rows", "default_interpret", "TILE_R", "LANE"]
 
 TILE_R = 256  # rows per grid step
 LANE = 128    # TPU lane width; K is padded to a multiple of this
 _WC = 8       # ELL-width slice per inner step
 
 
+def default_interpret() -> bool:
+    """Interpret the kernel unless the default backend is a TPU, the only
+    backend Mosaic compiles for."""
+    return jax.default_backend() != "tpu"
+
+
 def _kernel(lbl_ref, w_ref, out_ref, *, k_pad: int, width: int):
-    """Accumulate one (TILE_R, k_pad) score tile."""
-    acc = jnp.zeros((lbl_ref.shape[0], k_pad), jnp.float32)
-    iota_k = jax.lax.broadcasted_iota(jnp.int32, (1, 1, k_pad), 2)
+    """Accumulate one transposed (k_pad, TILE_R) score tile.
+
+    The ELL width runs along sublanes, so each step loads a (WC, TILE_R)
+    slab at a sublane offset that is a multiple of WC -- an aligned dynamic
+    slice Mosaic accepts at every width -- and folds its WC rows into the
+    tile one compare+select at a time."""
+    iota_k = jax.lax.broadcasted_iota(jnp.int32, (k_pad, 1), 0)
 
     def body(j, acc):
-        sl = lbl_ref[:, pl.dslice(j * _WC, _WC)]          # (TILE_R, WC)
-        sw = w_ref[:, pl.dslice(j * _WC, _WC)]            # (TILE_R, WC)
-        onehot = (sl[:, :, None] == iota_k).astype(jnp.float32)
-        return acc + jnp.sum(onehot * sw[:, :, None], axis=1)
+        base = pl.multiple_of(j * _WC, _WC)
+        sl = lbl_ref[pl.ds(base, _WC), :]                 # (WC, TILE_R)
+        sw = w_ref[pl.ds(base, _WC), :]                   # (WC, TILE_R)
+        for r in range(_WC):
+            acc = acc + jnp.where(sl[r:r + 1, :] == iota_k, sw[r:r + 1, :], 0.0)
+        return acc
 
-    steps = width // _WC
-    acc = jax.lax.fori_loop(0, steps, body, acc)
-    out_ref[...] = acc
+    acc = jnp.zeros((k_pad, lbl_ref.shape[1]), jnp.float32)
+    out_ref[...] = jax.lax.fori_loop(0, width // _WC, body, acc)
 
 
 @functools.partial(jax.jit, static_argnames=("k_pad", "interpret"))
@@ -71,14 +82,15 @@ def lp_score_rows(
     assert k_pad % LANE == 0, f"k_pad {k_pad} must be a multiple of {LANE}"
     assert W % _WC == 0, f"ELL width {W} must be a multiple of {_WC}"
     grid = (R // TILE_R,)
-    return pl.pallas_call(
+    out_t = pl.pallas_call(
         functools.partial(_kernel, k_pad=k_pad, width=W),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((TILE_R, W), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_R, W), lambda i: (i, 0)),
+            pl.BlockSpec((W, TILE_R), lambda i: (0, i)),
+            pl.BlockSpec((W, TILE_R), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((TILE_R, k_pad), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, k_pad), jnp.float32),
+        out_specs=pl.BlockSpec((k_pad, TILE_R), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((k_pad, R), jnp.float32),
         interpret=interpret,
-    )(lbl, w)
+    )(lbl.T, w.T)
+    return out_t.T

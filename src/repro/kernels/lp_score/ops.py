@@ -12,6 +12,7 @@ the fallback below the size threshold.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import numpy as np
 
@@ -20,7 +21,7 @@ import jax.numpy as jnp
 
 from ...graph.csr import GraphNP
 from ...graph.packing import EllPack, ell_pack
-from .lp_score import LANE, TILE_R, lp_score_rows
+from .lp_score import LANE, TILE_R, default_interpret, lp_score_rows
 from .ref import lp_score_rows_ref
 
 __all__ = [
@@ -82,9 +83,11 @@ def node_scores(
     k: int,
     ell: EllPack | None = None,
     use_pallas: bool = True,
-    interpret: bool = True,  # CPU container: interpret mode; False on real TPU
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
-    """S[v, b] for all nodes; Pallas on the row tiles, XLA for gather/segsum."""
+    """S[v, b] for all nodes; Pallas on the row tiles, XLA for gather/segsum.
+    ``interpret=None`` compiles the kernel on a TPU and interprets it
+    elsewhere (:func:`default_interpret`)."""
     if ell is None:
         ell = ell_pack(g, width=128, tile_rows=TILE_R)
     labels_ext = jnp.concatenate(
@@ -98,7 +101,7 @@ def node_scores(
         jnp.int32(g.n),
         k=k,
         use_pallas=use_pallas,
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )[: g.n]
 
 
@@ -227,7 +230,7 @@ def lp_refine_dense_round(
     move_fraction: float = 0.5,
     ell: EllPack | None = None,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> np.ndarray:
     """One fully synchronous LP refinement round using dense scores.
 
@@ -254,6 +257,6 @@ def lp_refine_dense_round(
         jnp.int32(g.n),
         k=k,
         use_pallas=use_pallas,
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )
     return np.asarray(new[: g.n])

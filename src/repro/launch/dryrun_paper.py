@@ -86,12 +86,11 @@ def main():
             )
             return out[0][None], out[1][None], out[2]
 
-        from repro.compat import shard_map as shard_map_compat
-
-        shmapped = shard_map_compat(
+        shmapped = jax.shard_map(
             body, mesh=mesh,
             in_specs=(spec,) * 15 + (P(),),
             out_specs=(spec, spec, P()),
+            check_vma=False,
         )
         jitted = jax.jit(
             shmapped,

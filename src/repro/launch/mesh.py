@@ -20,19 +20,8 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh for tests/examples (e.g. (2, 2) on 4 host devices).
-
-    ``axis_types`` only exists on newer jax (``jax.sharding.AxisType`` is
-    absent in 0.4.x, where Auto is already the default) — construct with it
-    when available, plainly otherwise.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                tuple(shape), tuple(axes),
-                axis_types=(axis_type.Auto,) * len(axes),
-            )
-        except TypeError:  # AxisType present but make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """Arbitrary mesh for tests/examples (e.g. (2, 2) on 4 host devices)."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )

@@ -184,9 +184,7 @@ def moe_ep(
     from jax.sharding import NamedSharding
 
     xs = jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(b_ax, s_ax, None)))
-    from ..compat import shard_map as shard_map_compat
-
-    y, aux = shard_map_compat(
+    y, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -197,6 +195,7 @@ def moe_ep(
             P(tp_axis, None, dp),
         ),
         out_specs=(P(b_ax, s_ax, None), P()),
+        check_vma=False,
     )(
         xs,
         params["router"],

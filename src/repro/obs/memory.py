@@ -471,22 +471,24 @@ def will_fit(
 ) -> dict:
     """Pre-upload capacity check: does (n, m, k) fit the device?
 
-    ``budget_bytes`` defaults to the backend's reported memory limit
-    (``device.memory_stats()['bytes_limit']``) when the platform exposes
-    one (TPU/GPU); on hosts without a limit (CPU) the check degrades to
-    reporting the estimate with ``fits=None`` unless a budget is given.
-    ``safety`` head-room multiplies the estimate (fragmentation + XLA
-    scratch)."""
+    ``budget_bytes`` defaults to the device's reported memory limit
+    (``device.memory_stats()['bytes_limit']``) on accelerators, where a
+    failing or limit-less ``memory_stats()`` raises; on the CPU, which has
+    no limit, the check reports the estimate with ``fits=None`` unless a
+    budget is given.  ``safety`` head-room multiplies the estimate
+    (fragmentation + XLA scratch)."""
     est = estimate_footprint(n, m, k, cfg, workload=workload)
     if budget_bytes is None:
-        try:
-            import jax
+        import jax
 
-            stats = jax.devices()[0].memory_stats()
-            if stats:
-                budget_bytes = stats.get("bytes_limit")
-        except Exception:
-            budget_bytes = None
+        dev = jax.devices()[0]
+        if dev.platform != "cpu":
+            stats = dev.memory_stats() or {}
+            if "bytes_limit" not in stats:
+                raise RuntimeError(
+                    f"{dev.device_kind} reports no bytes_limit: {stats}"
+                )
+            budget_bytes = int(stats["bytes_limit"])
     need = int(est["total"] * safety)
     return dict(
         estimate=est,
@@ -539,6 +541,7 @@ KNOWN_ALLOC_SITES: Dict[str, str] = {
     "core/engine.py::to_arena": "label_arenas",
     "core/engine.py::block_weights": "exempt:O(k) reduction scratch",
     "core/engine.py::cluster": "exempt:O(k) scalar/round scratch",
+    "core/engine.py::_weights_exact": "exempt:scalar-reduction scratch",
     "core/engine.py::refine": "exempt:O(k) block-weight scratch",
     # dynamic/store.py
     "dynamic/store.py::_dispatch_merge": "overlay_chunks",
@@ -547,5 +550,7 @@ KNOWN_ALLOC_SITES: Dict[str, str] = {
     "dynamic/store.py::view": "overlay_chunks",
     "dynamic/store.py::remove_nodes": "exempt:O(removed) validation upload",
     # deploy/extract.py
+    # graph/csr.py: sort_by_keys is only ever traced inside jitted kernels
+    "graph/csr.py::sort_by_keys": "exempt:traced inside jitted kernels",
     "deploy/extract.py::_labels_nb": "label_arenas",
 }

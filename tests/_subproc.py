@@ -1,4 +1,4 @@
-"""Helper: run a python snippet in a subprocess with N host devices."""
+"""Helper: run a python snippet in a subprocess with N virtual CPU devices."""
 
 import os
 import subprocess
@@ -10,6 +10,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 def run_with_devices(code: str, n_devices: int = 8, timeout: int = 900) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = SRC
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=timeout, env=env)

@@ -120,6 +120,10 @@ def _run_bench(extra_args, tmp, env_extra=None, json_name=None):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src") + os.pathsep + env.get(
         "PYTHONPATH", "")
+    # every run compiles afresh, as the recorded history did: a persistent
+    # cache filled by an earlier run would move compiles out of the timed
+    # windows of later runs only
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     if env_extra:
         env.update(env_extra)
     cmd = [sys.executable, os.path.join(ROOT, "benchmarks", "run.py"),

@@ -63,6 +63,7 @@ print("COLL-OK", c.collective_bytes)
 """
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=300, env=env)

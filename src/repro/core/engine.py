@@ -47,7 +47,7 @@ strong references to every level's graph until released.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, Optional, Tuple, Union
+from typing import Dict, List, Optional, Tuple, Union
 
 import numpy as np
 
@@ -153,6 +153,11 @@ class EngineStats(RegistryBackedStats):
         "h2d_bytes",            # host->device uploads the engine issued
         "d2h_bytes",            # device->host downloads (scalars + lazy
                                 # materializations of GraphDev/CoarseMap)
+        "host_reads",           # blocking device->host reads (``host_read``
+                                # and GraphDev/CoarseMap materializations)
+        "evo_grow_rounds",      # device GA grow-loop trips, summed over seed
+                                # steps; counted only where read (tracing)
+        "evo_grow_budget",      # grow_rounds_bound summed over seed steps
     )
     _SET_FIELDS = (
         "buckets",              # distinct (C, N, E, A, W)
@@ -246,6 +251,7 @@ class LPEngine:
         self._dense_keys = set()
         self._exact: Dict[int, tuple] = {}  # id(g) -> (g, GA weights exact)
         self._shard_steps: Dict[tuple, object] = {}
+        self._grow_rounds: List[jax.Array] = []  # unread seed-step counts
 
     @property
     def _iota(self) -> jax.Array:
@@ -285,7 +291,8 @@ class LPEngine:
                 graph=g, nw_arena=nw_arena, cluster_w=cw,
                 src=g.src, dst=g.indices, ew=g.ew,
                 integral=(g.m == 0 or g.ew_integral)
-                and float(jnp.sum(g.ew)) < 2**31,
+                and float(self.host_read(jnp.sum(g.ew), "arc_total"))
+                < 2**31,
             )
         else:
             nw = np.zeros(self.A, np.float32)
@@ -383,60 +390,61 @@ class LPEngine:
             return hit
         self.stats.pack_builds += 1
         self.stats.gather_builds += 1
-        order = make_order(g, mode, self.seed)
-        deg = g.degrees().astype(np.int64)[order]
-        node_chunk, C, N, E = plan_chunks(
-            deg, g.n, max_nodes=self.N,
-            max_edges=max(self._e_request, self.E_floor),
-            block=self.pack_block,
-        )
-        # same sticky bucket raising as the host path
-        self.C_bucket = max(self.C_bucket, _pow2(C))
-        Eb = max(self.E_floor, -(-E // 512) * 512)
-        self.E_floor = Eb
-        nodes, node_valid = layout_nodes(order, node_chunk, C, N, g.n)
-        # Tight pow2 LIVE-chunk prefix: the sweep's fori_loop only ever
-        # visits ``num_chunks`` live chunks, so dead chunks of the finest
-        # level's shared bucket are pure shape padding — emitting them would
-        # multiply the gather (and every sweep dispatch) by the dead/live
-        # ratio.  Coarse GraphDev levels therefore get their own pow2 chunk
-        # bucket; the few extra sweep shapes are reused across levels and
-        # V-cycles like every other bucket.
-        Cg = _pow2(C)
-        nodes = np.pad(
-            nodes, ((0, Cg - C), (0, self.N - N)), constant_values=g.n
-        )
-        node_valid = np.pad(node_valid, ((0, Cg - C), (0, self.N - N)))
-        nodes_d = jnp.asarray(nodes)
-        nv_d = jnp.asarray(node_valid)
-        self.stats.h2d_bytes += nodes.nbytes + node_valid.nbytes
-        gkey = (nodes.shape, g.indptr.shape[0], g.indices.shape[0], Eb)
-        if gkey not in self._gather_keys:
-            self._gather_keys.add(gkey)
-            self.stats.gather_compiles += 1
-            _obs_watchdog().note("engine.gather", gkey)
         with _obs_span(
-            "vcycle.pack", cat="vcycle", chunks=int(C), edge_bucket=int(Eb)
+            "vcycle.pack", cat="vcycle", mode=mode, n=int(g.n), host=False
         ) as sp:
+            order = make_order(g, mode, self.seed)
+            deg = g.degrees().astype(np.int64)[order]
+            node_chunk, C, N, E = plan_chunks(
+                deg, g.n, max_nodes=self.N,
+                max_edges=max(self._e_request, self.E_floor),
+                block=self.pack_block,
+            )
+            # same sticky bucket raising as the host path
+            self.C_bucket = max(self.C_bucket, _pow2(C))
+            Eb = max(self.E_floor, -(-E // 512) * 512)
+            self.E_floor = Eb
+            nodes, node_valid = layout_nodes(order, node_chunk, C, N, g.n)
+            # Tight pow2 LIVE-chunk prefix: the sweep's fori_loop only ever
+            # visits ``num_chunks`` live chunks, so dead chunks of the
+            # finest level's shared bucket are pure shape padding — emitting
+            # them would multiply the gather (and every sweep dispatch) by
+            # the dead/live ratio.  Coarse GraphDev levels therefore get
+            # their own pow2 chunk bucket; the few extra sweep shapes are
+            # reused across levels and V-cycles like every other bucket.
+            Cg = _pow2(C)
+            nodes = np.pad(
+                nodes, ((0, Cg - C), (0, self.N - N)), constant_values=g.n
+            )
+            node_valid = np.pad(node_valid, ((0, Cg - C), (0, self.N - N)))
+            nodes_d = jnp.asarray(nodes)
+            nv_d = jnp.asarray(node_valid)
+            self.stats.h2d_bytes += nodes.nbytes + node_valid.nbytes
+            gkey = (nodes.shape, g.indptr.shape[0], g.indices.shape[0], Eb)
+            if gkey not in self._gather_keys:
+                self._gather_keys.add(gkey)
+                self.stats.gather_compiles += 1
+                _obs_watchdog().note("engine.gather", gkey)
+            sp.set(chunks=int(C), edge_bucket=int(Eb))
             edge_dst, edge_w, edge_slot, edge_valid = gather_pack_device(
                 nodes_d, nv_d, g.indptr, g.indices, g.ew, jnp.int32(g.n), E=Eb
             )
             sp.sync_on(edge_valid)
-        dp = _DevicePack(
-            graph=g,
-            nodes=nodes_d,
-            node_valid=nv_d,
-            edge_dst=edge_dst,
-            edge_w=edge_w,
-            edge_src_slot=edge_slot,
-            edge_valid=edge_valid,
-            num_chunks=C,
-            shape=(Cg, self.N, Eb),
-        )
-        _mem_account("chunk_packs", dp.nodes, dp.node_valid, dp.edge_dst,
-                     dp.edge_w, dp.edge_src_slot, dp.edge_valid)
-        self._packs[key] = dp
-        return dp
+            dp = _DevicePack(
+                graph=g,
+                nodes=nodes_d,
+                node_valid=nv_d,
+                edge_dst=edge_dst,
+                edge_w=edge_w,
+                edge_src_slot=edge_slot,
+                edge_valid=edge_valid,
+                num_chunks=C,
+                shape=(Cg, self.N, Eb),
+            )
+            _mem_account("chunk_packs", dp.nodes, dp.node_valid, dp.edge_dst,
+                         dp.edge_w, dp.edge_src_slot, dp.edge_valid)
+            self._packs[key] = dp
+            return dp
 
     def _ell(self, g: AnyGraph) -> _DeviceEll:
         hit = self._ells.get(id(g))
@@ -596,7 +604,7 @@ class LPEngine:
             self.stats.h2d_bytes += r.nbytes
         with _obs_span(
             "vcycle.sweep", cat="vcycle", mode="cluster", n=int(g.n),
-            iters=int(iters),
+            m=int(g.m), iters=int(iters), chunks=int(dp.num_chunks),
         ) as sp:
             labels, _, _ = self._sweep(
                 dp, self._iota, ar.cluster_w, ar.nw_arena, r_dev, U, seed,
@@ -631,7 +639,7 @@ class LPEngine:
         w0 = bw.at[k].set(jnp.inf)
         with _obs_span(
             "vcycle.sweep", cat="vcycle", mode="refine", n=int(g.n),
-            iters=int(iters),
+            m=int(g.m), iters=int(iters), chunks=int(dp.num_chunks),
         ) as sp:
             lab_out, _, _ = self._sweep(
                 dp, lab, w0, ar.nw_arena, jnp.zeros(1, jnp.int32), U, seed,
@@ -670,7 +678,7 @@ class LPEngine:
             _obs_watchdog().note("engine.dense", dkey)
         with _obs_span(
             "vcycle.sweep", cat="vcycle", mode="dense", n=int(g.n),
-            iters=int(iters),
+            m=int(g.m), iters=int(iters),
         ) as sp:
             for r in range(iters):
                 lab = dense_round_device(
@@ -952,13 +960,16 @@ class LPEngine:
             return hit[1]
         ar = self._arena(g)
         if isinstance(g, GraphDev):
-            nw_ok = (bool(jnp.all(g.nw == jnp.round(g.nw)))
-                     and float(jnp.sum(g.nw)) < 2**24)
+            nw_ok = (bool(self.host_read(jnp.all(g.nw == jnp.round(g.nw)),
+                                         "weights_exact"))
+                     and float(self.host_read(jnp.sum(g.nw), "node_total"))
+                     < 2**24)
             wdeg = 0.0
             if ar.integral and g.m:
                 cs = jnp.concatenate([jnp.zeros((1,), jnp.int32),
                                       jnp.cumsum(g.ew.astype(jnp.int32))])
-                wdeg = float(jnp.max(cs[g.indptr[1:]] - cs[g.indptr[:-1]]))
+                wdeg = float(self.host_read(
+                    jnp.max(cs[g.indptr[1:]] - cs[g.indptr[:-1]]), "max_wdeg"))
         else:
             nw_ok = (bool(np.all(g.nw == np.round(g.nw)))
                      and float(g.nw.sum()) < 2**24)
@@ -1034,7 +1045,9 @@ class LPEngine:
             _obs_watchdog().note("engine.evo", skey)
         from .evolutionary import grow_rounds_bound
 
-        labs, keys = evo_seed_step(
+        budget = grow_rounds_bound(n, k, g.m)
+        self.stats.evo_grow_budget += budget
+        labs, keys, rounds = evo_seed_step(
             dp.nodes, dp.node_valid, dp.edge_dst, dp.edge_w,
             dp.edge_src_slot, dp.edge_valid,
             jnp.asarray(seed_lab), jnp.asarray(seed_mask),
@@ -1042,9 +1055,10 @@ class LPEngine:
             jnp.float32(cfg.Lmax), jnp.int32(seed_eff),
             jnp.int32(I), jnp.int32(P), jnp.int32(n), jnp.int32(k),
             jnp.int32(dp.num_chunks),
-            jnp.int32(grow_rounds_bound(n, k, g.m)),
+            jnp.int32(budget),
             refine_iters=cfg.refine_iters, Kb=Kb,
         )
+        self._grow_rounds.append(rounds)    # read only by read_grow_rounds
         _mem_account("evo_population", labs, keys)
         D = jax.device_count()
         if shard and G > 0 and D > 1 and I % D == 0:
@@ -1094,8 +1108,7 @@ class LPEngine:
         Sb_loc = _pow2(S_loc)
         Kb = _pow2(k + 1)
         Ib_loc = _pow2(I_loc)
-        lab_h = np.asarray(labs)
-        key_h = np.asarray(keys)
+        lab_h, key_h = self.host_read((labs, keys), "population")
         self.stats.d2h_bytes += lab_h.nbytes + key_h.nbytes
         lab_sh = np.full((D, Sb_loc, Ab), k, np.int32)
         key_sh = np.full((D, Sb_loc), 2**31 - 1, np.int32)
@@ -1136,8 +1149,7 @@ class LPEngine:
                 jnp.int32(dp.num_chunks),
             )
         # flatten back to island-major flat order (gossip already global)
-        lab_fh = np.asarray(labs_d)
-        key_fh = np.asarray(keys_d)
+        lab_fh, key_fh = self.host_read((labs_d, keys_d), "population")
         self.stats.d2h_bytes += lab_fh.nbytes + key_fh.nbytes
         Sb = _pow2(I * P)
         lab_out = np.full((Sb, Ab), k, np.int32)
@@ -1147,10 +1159,12 @@ class LPEngine:
             key_out[d * S_loc:(d + 1) * S_loc] = key_fh[d, :S_loc]
         return jnp.asarray(lab_out), jnp.asarray(key_out)
 
-    def evolve_oracle(self, g: AnyGraph, cfg, trace=None) -> np.ndarray:
+    def evolve_oracle(self, g: AnyGraph, cfg, trace=None,
+                      grow_rounds=None) -> np.ndarray:
         """Sequential host-numpy oracle on the SAME pack/arc arrays the
         device path dispatches — the parity reference and the
-        host-sequential baseline of the ``evo_hot`` benchmark."""
+        host-sequential baseline of the ``evo_hot`` benchmark
+        (``trace``/``grow_rounds``: see ``evolve_batched_numpy``)."""
         from .evolutionary import EvoInputs, evolve_batched_numpy
 
         dp, ar, Ab = self._evo_arrays(g)
@@ -1171,7 +1185,8 @@ class LPEngine:
             deg=deg,
             n=g.n,
         )
-        return evolve_batched_numpy(inp, cfg, trace=trace)
+        return evolve_batched_numpy(inp, cfg, trace=trace,
+                                    grow_rounds=grow_rounds)
 
     # ------------------------------------------------------------ contraction
 
@@ -1245,7 +1260,8 @@ class LPEngine:
             )
             # the only host sync of the level: all four scalars in one
             # transfer (it also bounds the span — no extra block needed)
-            n_c, m_c, nwmax, ewmax = jax.device_get((n_c, m_c, nwmax, ewmax))
+            n_c, m_c, nwmax, ewmax = self.host_read(
+                (n_c, m_c, nwmax, ewmax), "contract_sizes")
         n_c, m_c, nwmax, ewmax = int(n_c), int(m_c), float(nwmax), float(ewmax)
         self.stats.d2h_bytes += 16
         Ncb = _pow2(max(n_c, 8))
@@ -1279,7 +1295,35 @@ class LPEngine:
         return out
 
     def _note_d2h(self, nbytes: int) -> None:
+        """A GraphDev/CoarseMap materialization: one read, counted after it."""
         self.stats.d2h_bytes += int(nbytes)
+        self.stats.host_reads += 1
+
+    def host_read(self, x, what: str):
+        """Every blocking device->host read of the partition path goes
+        through here: ``x`` is a device array (-> numpy) or a tuple of them
+        (-> tuple of numpy), counted in ``host_reads`` and spanned as
+        ``host.read``.  The read is the caller's; this adds none."""
+        self.stats.host_reads += 1
+        if isinstance(x, tuple):
+            nbytes = sum(int(a.nbytes) for a in x)
+            with _obs_span("host.read", cat="host", what=what, bytes=nbytes):
+                return tuple(jax.device_get(x))
+        with _obs_span("host.read", cat="host", what=what,
+                       bytes=int(x.nbytes)):
+            return np.asarray(x)
+
+    def read_grow_rounds(self) -> int:
+        """Fold the device GA's pending grow-loop trip counts (one scalar
+        per seed step, left on the device) into ``evo_grow_rounds``, in one
+        read, and return the total.  Tracing alone calls this, at
+        ``partition`` close with the labels already on the host, so with
+        tracing off the counts are never read and nothing syncs."""
+        if self._grow_rounds:
+            self.stats.evo_grow_rounds += sum(
+                int(r) for r in jax.device_get(self._grow_rounds))
+            self._grow_rounds.clear()
+        return self.stats.evo_grow_rounds
 
     # --------------------------------------------------------- device helpers
 
@@ -1341,19 +1385,19 @@ class LPEngine:
         """Edge cut of arena labels, evaluated on device (one scalar sync);
         exact for integral weights at any size."""
         ar = self._arena(g)
-        return float(cut_from_arcs_jnp(
+        return float(self.host_read(cut_from_arcs_jnp(
             labels, ar.src, ar.dst, ar.ew, integral=ar.integral
-        ))
+        ), "cut"))
 
     def block_weights(self, g: AnyGraph, labels: jax.Array, k: int) -> np.ndarray:
         ar = self._arena(g)
         bw = jnp.zeros((k + 1,), jnp.float32).at[jnp.minimum(labels, k)].add(
             ar.nw_arena
         )
-        return np.asarray(bw[:k])
+        return self.host_read(bw[:k], "block_weights")
 
     def to_host(self, labels: jax.Array, n: int) -> np.ndarray:
-        return np.asarray(labels[:n])
+        return self.host_read(labels[:n], "labels")
 
     # ---------------------------------------------------------------- metrics
 
@@ -1396,6 +1440,9 @@ class LPEngine:
             audit_bucket_count=self.stats.audit_bucket_count,
             h2d_bytes=self.stats.h2d_bytes,
             d2h_bytes=self.stats.d2h_bytes,
+            host_reads=self.stats.host_reads,
+            evo_grow_rounds=self.stats.evo_grow_rounds,
+            evo_grow_budget=self.stats.evo_grow_budget,
             arena=self.A,
             chunk_bucket=(self.C_bucket, self.N, self.E_floor),
         )

@@ -110,7 +110,8 @@ def _greedy_one(s_idx, src, dst, ew, nw, deg_f, n, k, Kb, Lmax, seed, rounds):
 
     ``rounds`` is the traced degree/diameter-proportional budget
     (``evolutionary.grow_rounds_bound``) — one executable still serves
-    every coarsest graph in the bucket."""
+    every coarsest graph in the bucket.  Returns the labels and the rounds
+    this individual ran."""
     Ab = nw.shape[0]
     iota = jnp.arange(Ab, dtype=jnp.int32)
     kio = jnp.arange(Kb, dtype=jnp.int32)
@@ -162,13 +163,13 @@ def _greedy_one(s_idx, src, dst, ew, nw, deg_f, n, k, Kb, Lmax, seed, rounds):
         r, lab, prev = state
         return r + 1, grow_round(r, lab), _unas_count(lab)
 
-    _, lab, _ = lax.while_loop(
+    trips, lab, _ = lax.while_loop(
         grow_cond, grow_body, (jnp.int32(0), lab0, jnp.int32(_IMAX))
     )
     unas = (lab < 0) & (iota < n)
     pos = jnp.cumsum(unas.astype(jnp.int32)) - 1
     lab = jnp.where(unas, pos % k, lab)
-    return jnp.where(iota < n, lab, k).astype(jnp.int32)
+    return jnp.where(iota < n, lab, k).astype(jnp.int32), trips
 
 
 def _gain_round(src, dst, ew, nw, lab, n, k, Kb, Lmax, base_score, base_gate):
@@ -375,12 +376,17 @@ def evo_seed_step(
     unseeded rows (``grow_rounds`` frontier-round budget — traced, computed
     by ``evolutionary.grow_rounds_bound``), verbatim seed rows (the
     V-cycle's projected solution), batched refine, int32 fitness keys.  ONE
-    executable per ``(pack bucket, Sb, Ab, Kb)`` shape."""
+    executable per ``(pack bucket, Sb, Ab, Kb)`` shape.
+
+    Returns ``(labels, keys, grow_trips)``: the last is the number of trips
+    the device made through the grow loop, the max over all ``Sb`` rows of
+    the rounds each ran (under ``vmap`` the loop runs until the slowest
+    row stops)."""
     Sb, Ab = seed_labels.shape
     iota_s = jnp.arange(Sb, dtype=jnp.int32)
     valid_s = iota_s < I * P
     pack = (nodes, node_valid, edge_dst, edge_w, edge_src_slot, edge_valid)
-    grown = jax.vmap(
+    grown, trips = jax.vmap(
         lambda s: _greedy_one(
             s, src, dst, ew, nw, deg_f, n, k, Kb, Lmax, seed, grow_rounds
         )
@@ -394,7 +400,7 @@ def evo_seed_step(
         lambda lab: _evaluate(lab, src, dst, ew, nw, k, Kb, Lmax)
     )(labs)
     keys = jnp.where(valid_s, keys, jnp.int32(_IMAX))
-    return labs, keys
+    return labs, keys, jnp.max(trips)
 
 
 def _generation_core(

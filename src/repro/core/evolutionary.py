@@ -319,10 +319,12 @@ def _evaluate_np(inp: EvoInputs, lab, k: int, Kb: int, Lmax) -> tuple:
     return key, float(cut), feas
 
 
-def _greedy_grow_np(inp: EvoInputs, s: int, seed: int, k: int, Kb: int, Lmax):
+def _greedy_grow_np(inp: EvoInputs, s: int, seed: int, k: int, Kb: int, Lmax,
+                    grow_rounds: Optional[list] = None):
     """Batched greedy growing, one individual: hash-scored degree-biased
     seeds, degree/diameter-proportional synchronous frontier rounds
-    (:func:`grow_rounds_bound`), round-robin leftovers."""
+    (:func:`grow_rounds_bound`), round-robin leftovers.  Appends the
+    rounds it ran to ``grow_rounds`` when given."""
     n, Ab = inp.n, inp.Ab
     iota = np.arange(Ab, dtype=np.int32)
     kio = np.arange(Kb, dtype=np.int32)
@@ -338,6 +340,7 @@ def _greedy_grow_np(inp: EvoInputs, s: int, seed: int, k: int, Kb: int, Lmax):
     lab = np.where((rank < k) & (iota < n), rank, np.int32(-1)).astype(np.int32)
     rounds = grow_rounds_bound(n, k, int(inp.deg[:n].sum()))
     prev_cnt = None
+    ran = 0
     for r in range(rounds):
         unas = (lab < 0) & (iota < n)
         cnt = int(unas.sum())
@@ -345,6 +348,7 @@ def _greedy_grow_np(inp: EvoInputs, s: int, seed: int, k: int, Kb: int, Lmax):
             break  # converged / stalled: further rounds are no-ops (the
             # device while_loop exits on exactly these conditions)
         prev_cnt = cnt
+        ran += 1
         conn = np.zeros((Ab, Kb), np.float32)
         tgt = lab[inp.dst]
         mask = tgt >= 0
@@ -363,6 +367,8 @@ def _greedy_grow_np(inp: EvoInputs, s: int, seed: int, k: int, Kb: int, Lmax):
         b = np.argmax(score, axis=1).astype(np.int32)
         has = score[iota, b] > np.float32(-5e29)
         lab = np.where(unas & has, b, lab).astype(np.int32)
+    if grow_rounds is not None:
+        grow_rounds.append(ran)
     unas = (lab < 0) & (iota < n)
     pos = np.cumsum(unas.astype(np.int32), dtype=np.int64).astype(np.int32) - 1
     lab = np.where(unas, pos % np.int32(k), lab)
@@ -512,7 +518,8 @@ def _worst_member_np(keys, i: int, P: int) -> int:
 
 
 def evolve_batched_numpy(
-    inp: EvoInputs, cfg: EvoConfig, trace: Optional[list] = None
+    inp: EvoInputs, cfg: EvoConfig, trace: Optional[list] = None,
+    grow_rounds: Optional[list] = None,
 ) -> np.ndarray:
     """Sequential numpy oracle of the batched island GA (device spec twin).
 
@@ -520,7 +527,8 @@ def evolve_batched_numpy(
     ``trace`` given, appends ``(gen, island, base_key, child_key)`` per
     offspring *before* elitism — the offspring-never-worse-than-better-parent
     property is then ``min(child_key, base_key) <= base_key`` post-elitism,
-    asserted in tests.
+    asserted in tests.  With ``grow_rounds`` given, appends the frontier
+    rounds each grown individual ran.
     """
     k, Lmax = cfg.k, np.float32(cfg.Lmax)
     Kb = _pow2(k + 1)
@@ -538,7 +546,7 @@ def evolve_batched_numpy(
                 dtype=np.int32,
             )
         else:
-            lab = _greedy_grow_np(inp, s, seed, k, Kb, Lmax)
+            lab = _greedy_grow_np(inp, s, seed, k, Kb, Lmax, grow_rounds)
             lab = _refine_np(inp, lab, s, 0, seed, cfg.refine_iters, k, Kb, Lmax)
         labs.append(lab)
         keys.append(_evaluate_np(inp, lab, k, Kb, Lmax)[0])

@@ -33,10 +33,11 @@ import numpy as np
 
 from ..graph.csr import GraphDev, GraphNP
 from ..graph.packing import chunk_geometry
+from ..obs import gc_spans as _gc_spans
 from ..obs import span as _obs_span
 from .contraction import CoarseMap, contract, project_labels
 from .engine import LPEngine
-from .evolutionary import EvoConfig, evolve
+from .evolutionary import EvoConfig, evolve, grow_rounds_bound
 from .initial_partition import repair_balance
 from .label_propagation import lp_cluster, lp_refine, sclap_numpy
 from .metrics import cut_np, imbalance_np, lmax
@@ -180,8 +181,9 @@ def _cluster(g, U, iters, seed, restrict, cfg, eng=None) -> np.ndarray:
         )
         return lp_cluster_distributed(plan, U=U, iters=iters, seed=seed)
     if eng is not None:
-        return np.asarray(
-            eng.cluster(g, U=U, iters=iters, seed=seed, restrict=restrict)
+        return eng.host_read(
+            eng.cluster(g, U=U, iters=iters, seed=seed, restrict=restrict),
+            "labels",
         )
     max_nodes, max_edges = chunk_geometry(g.n, g.m, cfg.target_chunks)
     return lp_cluster(
@@ -229,53 +231,69 @@ def _uncoarsen(g, hierarchy, lab, k, L, cfg, rng, eng):
     """
     lab_dev = None  # engine arena labels, device-resident once set
     for gg_f, C in reversed(hierarchy):
-        seed_r = int(rng.integers(1 << 30))
-        eng_level = (
-            eng is not None
-            and cfg.engine in ("auto", "jnp")
-            and not _use_numpy(gg_f, cfg)
-        )
-        if eng_level:
-            with _obs_span(
-                "vcycle.project", cat="vcycle", n=int(gg_f.n)
-            ) as sp:
-                lab_dev = eng.project(
-                    lab_dev if lab_dev is not None else lab, C, fill=k
-                )
-                sp.sync_on(lab_dev)
-            lab = None
-            before = eng.cut(gg_f, lab_dev)
-            if cfg.refine_engine == "dense" and gg_f.n >= cfg.dense_min_n:
-                ref = eng.refine_dense(
-                    gg_f, lab_dev, k, L, cfg.lp_iters_refine, seed_r
-                )
-            else:
-                ref = eng.refine(gg_f, lab_dev, k, L, cfg.lp_iters_refine, seed_r)
-            # monotonicity guard: chunked-synchronous LP may oscillate; keep
-            # the refined labels only if they did not worsen the cut (unless
-            # they were needed to restore feasibility)
-            bw_ref = float(eng.block_weights(gg_f, ref, k).max())
-            bw_old = float(eng.block_weights(gg_f, lab_dev, k).max())
-            if eng.cut(gg_f, ref) <= before or bw_old > L >= bw_ref:
-                lab_dev = ref
-        else:
-            gg_h = gg_f.to_host() if isinstance(gg_f, GraphDev) else gg_f
-            C_np = C.host() if isinstance(C, CoarseMap) else C
-            if lab is None:  # leaving the device path (defensive; host levels
-                lab = np.asarray(lab_dev)  # precede device levels in practice)
-                lab_dev = None
-            elif not isinstance(lab, np.ndarray):
-                lab = np.asarray(lab)  # device-evo labels entering a host level
-            lab = project_labels(lab, C_np)
-            before = cut_np(gg_h, lab)
-            ref = _refine(gg_h, lab, k, L, cfg.lp_iters_refine, seed_r, cfg)
-            bw_ref = np.bincount(ref, weights=gg_h.nw, minlength=k).max()
-            bw_old = np.bincount(lab, weights=gg_h.nw, minlength=k).max()
-            if cut_np(gg_h, ref) <= before or bw_old > L >= bw_ref:
-                lab = ref
+        with _obs_span("vcycle.uncoarsen", cat="vcycle", n=int(gg_f.n),
+                       m=int(gg_f.m)):
+            lab, lab_dev = _uncoarsen_level(
+                gg_f, C, lab, lab_dev, k, L, cfg, rng, eng
+            )
     if lab is None:
         lab = eng.to_host(lab_dev, g.n)
-    return np.asarray(lab)  # device-evo labels may reach here untouched
+    elif not isinstance(lab, np.ndarray):  # device-evo labels, no hierarchy
+        lab = eng.host_read(lab, "labels")
+    return lab
+
+
+def _uncoarsen_level(gg_f, C, lab, lab_dev, k, L, cfg, rng, eng):
+    """One uncoarsening level: project, refine, keep the better labels.
+    Returns ``(lab, lab_dev)``: host labels or the device arena labels."""
+    seed_r = int(rng.integers(1 << 30))
+    eng_level = (
+        eng is not None
+        and cfg.engine in ("auto", "jnp")
+        and not _use_numpy(gg_f, cfg)
+    )
+    if eng_level:
+        with _obs_span(
+            "vcycle.project", cat="vcycle", n=int(gg_f.n)
+        ) as sp:
+            lab_dev = eng.project(
+                lab_dev if lab_dev is not None else lab, C, fill=k
+            )
+            sp.sync_on(lab_dev)
+        before = eng.cut(gg_f, lab_dev)
+        if cfg.refine_engine == "dense" and gg_f.n >= cfg.dense_min_n:
+            ref = eng.refine_dense(
+                gg_f, lab_dev, k, L, cfg.lp_iters_refine, seed_r
+            )
+        else:
+            ref = eng.refine(gg_f, lab_dev, k, L, cfg.lp_iters_refine, seed_r)
+        # monotonicity guard: chunked-synchronous LP may oscillate; keep
+        # the refined labels only if they did not worsen the cut (unless
+        # they were needed to restore feasibility)
+        bw_ref = float(eng.block_weights(gg_f, ref, k).max())
+        bw_old = float(eng.block_weights(gg_f, lab_dev, k).max())
+        if eng.cut(gg_f, ref) <= before or bw_old > L >= bw_ref:
+            lab_dev = ref
+        return None, lab_dev
+    gg_h = gg_f.to_host() if isinstance(gg_f, GraphDev) else gg_f
+    C_np = C.host() if isinstance(C, CoarseMap) else C
+    if lab is None:  # leaving the device path (defensive; host levels
+        lab = eng.host_read(lab_dev, "labels")  # precede device levels)
+        lab_dev = None
+    elif not isinstance(lab, np.ndarray):
+        lab = eng.host_read(lab, "labels")  # device-evo labels, host level
+    lab = project_labels(lab, C_np)
+    before = cut_np(gg_h, lab)
+    ref = _refine(gg_h, lab, k, L, cfg.lp_iters_refine, seed_r, cfg)
+    bw_ref = np.bincount(ref, weights=gg_h.nw, minlength=k).max()
+    bw_old = np.bincount(lab, weights=gg_h.nw, minlength=k).max()
+    if cut_np(gg_h, ref) <= before or bw_old > L >= bw_ref:
+        lab = ref
+    return lab, lab_dev
+
+
+# engine counters a traced ``partition`` span closes with
+_SPAN_COUNTERS = ("host_reads", "evo_grow_rounds", "evo_grow_budget")
 
 
 def partition(g, cfg: PartitionerConfig) -> PartitionReport:
@@ -286,7 +304,19 @@ def partition(g, cfg: PartitionerConfig) -> PartitionReport:
     (no arena re-upload), and only the host-side finalization steps
     (type detection, balance repair, final metrics) touch the cached
     ``to_host()`` view.  This is the dynamic session's escalation path.
+
+    The whole call is the ``partition`` span (args n, m, k, seed, vcycles);
+    when tracing, it closes with the engine's ``host_reads``,
+    ``evo_grow_rounds`` and ``evo_grow_budget`` as metadata.
     """
+    with _obs_span(
+        "partition", cat="partition", n=int(g.n), m=int(g.m), k=int(cfg.k),
+        seed=int(cfg.seed), vcycles=int(cfg.vcycles),
+    ) as sp, _gc_spans():
+        return _partition(g, cfg, sp)
+
+
+def _partition(g, cfg: PartitionerConfig, sp) -> PartitionReport:
     t0 = time.time()
     rng = np.random.default_rng(cfg.seed)
     k = cfg.k
@@ -342,43 +372,14 @@ def partition(g, cfg: PartitionerConfig) -> PartitionReport:
         for lev in range(cfg.max_levels):
             if gg.n <= coarsest_target:
                 break
-            seed = int(rng.integers(1 << 30))
-            if isinstance(gg, GraphDev) and (_use_numpy(gg, cfg) or not dev_coarsen):
-                # below the engine threshold (or host coarsening requested):
-                # hand the level chain back to the host engines (lazy
-                # materialization, one download — cached on the finest level)
-                gg = gg.to_host()
-                if restrict is not None and not isinstance(restrict, np.ndarray):
-                    restrict = np.asarray(restrict[: gg.n]).astype(np.int64)
-            dev_level = dev_coarsen and not _use_numpy(gg, cfg)
-            if dev_level:
-                nw_max = gg.nw_max if isinstance(gg, GraphDev) else float(gg.nw.max())
-                U = max(nw_max, L / f)
-                if restrict is not None and isinstance(restrict, np.ndarray):
-                    restrict = eng.to_arena(restrict, gg.n, fill=-1)
-                clus = eng.cluster(
-                    gg, U=U, iters=cfg.lp_iters_coarsen, seed=seed,
-                    restrict=restrict,
+            with _obs_span("vcycle.level", cat="vcycle", level=lev,
+                           n=int(gg.n), m=int(gg.m)):
+                coarse, C, gg, restrict = _coarsen_level(
+                    gg, restrict, L, f, k, cfg, rng, eng, dev_coarsen
                 )
-                coarse, C = eng.contract(gg, clus)
-                # stall, or overshoot below k (the initial partitioner needs
-                # at least k coarse nodes to seed blocks from)
-                if coarse.n >= cfg.shrink_stall * gg.n or coarse.n < k:
-                    break
-                hierarchy.append((gg, C))
-                if restrict is not None:
-                    restrict = eng.project_restrict(C, restrict)
-            else:
-                U = max(float(gg.nw.max()), L / f)
-                clus = _cluster(gg, U, cfg.lp_iters_coarsen, seed, restrict, cfg, eng)
-                coarse, C = contract(gg, clus)
-                if coarse.n >= cfg.shrink_stall * gg.n or coarse.n < k:
-                    break
-                hierarchy.append((gg, C))
-                if restrict is not None:
-                    rc = np.zeros(coarse.n, dtype=np.int64)
-                    rc[C] = restrict  # consistent: clusters never straddle blocks
-                    restrict = rc
+            if coarse is None:  # stall, or overshoot below k
+                break
+            hierarchy.append((gg, C))
             if cycle == 0 and lev == 0:
                 shrink_first = coarse.n / max(gg.n, 1)
             gg = coarse
@@ -389,7 +390,8 @@ def partition(g, cfg: PartitionerConfig) -> PartitionReport:
         seeds = []
         if cur_labels is not None:
             if not isinstance(restrict, np.ndarray):
-                restrict = np.asarray(restrict[: gg.n]).astype(np.int64)
+                restrict = eng.host_read(
+                    restrict[: gg.n], "restrict").astype(np.int64)
             seeds.append(restrict.astype(np.int32))  # projected current solution
         evo = EvoConfig(
             k=k,
@@ -407,23 +409,31 @@ def partition(g, cfg: PartitionerConfig) -> PartitionReport:
             and cfg.evo_engine in ("auto", "device")
             and eng.can_evolve_device(gg, k, cfg.islands, cfg.pop_per_island)
         )
-        if use_dev_evo:
-            # the coarsest stage consumes the still-resident GraphDev (or the
-            # finest GraphNP) directly: batched device GA, labels stay on
-            # device into the uncoarsening projection
-            lab = eng.evolve_device(gg, evo, shard=cfg.evo_shard_islands)
-        else:
-            gg_host = gg.to_host() if isinstance(gg, GraphDev) else gg
-            lab = evolve(gg_host, evo)
+        with _obs_span(
+            "vcycle.evolve", cat="vcycle", device=use_dev_evo, n=int(gg.n),
+            m=int(gg.m), islands=cfg.islands, pop=cfg.pop_per_island,
+            generations=cfg.generations,
+            grow_budget=grow_rounds_bound(gg.n, k, gg.m) if use_dev_evo else 0,
+        ) as ev:
+            if use_dev_evo:
+                # the coarsest stage consumes the still-resident GraphDev (or
+                # the finest GraphNP) directly: batched device GA, labels stay
+                # on device into the uncoarsening projection
+                lab = eng.evolve_device(gg, evo, shard=cfg.evo_shard_islands)
+                ev.sync_on(lab)
+            else:
+                gg_host = gg.to_host() if isinstance(gg, GraphDev) else gg
+                lab = evolve(gg_host, evo)
 
         # ---------------- uncoarsening + local search ----------------
         lab = _uncoarsen(g, hierarchy, lab, k, L, cfg, rng, eng)
-        if cfg.fm_finest and g.n <= cfg.fm_finest_max_n:
-            from .fm import fm_refine
+        with _obs_span("partition.finalize", cat="partition", cycle=cycle):
+            if cfg.fm_finest and g.n <= cfg.fm_finest_max_n:
+                from .fm import fm_refine
 
-            lab = fm_refine(gh, lab, k, L, seed=int(rng.integers(1 << 30)))
-        lab = repair_balance(gh, lab, k, L, seed=cfg.seed)
-        c = cut_np(gh, lab)
+                lab = fm_refine(gh, lab, k, L, seed=int(rng.integers(1 << 30)))
+            lab = repair_balance(gh, lab, k, L, seed=cfg.seed)
+            c = cut_np(gh, lab)
         cycle_cuts.append(c)
         cur_labels = lab.astype(np.int64)
         if c < best_cut:
@@ -431,16 +441,64 @@ def partition(g, cfg: PartitionerConfig) -> PartitionReport:
         if eng is not None:
             eng.evict(keep=(g,))  # coarse graphs never recur across cycles
 
+    with _obs_span("partition.finalize", cat="partition", cycle=-1):
+        imbalance = imbalance_np(gh, best_labels, k)
+        feasible = bool(
+            np.bincount(best_labels, weights=gh.nw, minlength=k).max() <= L + 1e-6
+        )
+    if eng is not None and sp.active:
+        # tracing only: the labels are on the host, so reading the GA's
+        # grow-loop trip counts stalls nothing
+        eng.read_grow_rounds()
+        sp.set(**{c: getattr(eng.stats, c) for c in _SPAN_COUNTERS})
     return PartitionReport(
         labels=best_labels,
         cut=float(best_cut),
-        imbalance=imbalance_np(gh, best_labels, k),
-        feasible=bool(
-            np.bincount(best_labels, weights=gh.nw, minlength=k).max() <= L + 1e-6
-        ),
+        imbalance=imbalance,
+        feasible=feasible,
         level_sizes=level_sizes,
         shrink_first=shrink_first,
         cycle_cuts=cycle_cuts,
         seconds=time.time() - t0,
         engine_stats=eng.stats_dict() if eng is not None else None,
     )
+
+
+def _coarsen_level(gg, restrict, L, f, k, cfg, rng, eng, dev_coarsen):
+    """One coarsening level: cluster, contract.  Returns ``(coarse, C, gg,
+    restrict)`` — ``gg`` as clustered (handed back to the host below the
+    engine threshold), ``restrict`` pushed down to ``coarse`` — or
+    ``coarse`` None when contraction stalls or overshoots below k."""
+    seed = int(rng.integers(1 << 30))
+    if isinstance(gg, GraphDev) and (_use_numpy(gg, cfg) or not dev_coarsen):
+        # below the engine threshold (or host coarsening requested): hand
+        # the level chain back to the host engines (lazy materialization,
+        # one download — cached on the finest level)
+        gg = gg.to_host()
+        if restrict is not None and not isinstance(restrict, np.ndarray):
+            restrict = eng.host_read(
+                restrict[: gg.n], "restrict").astype(np.int64)
+    if dev_coarsen and not _use_numpy(gg, cfg):
+        nw_max = gg.nw_max if isinstance(gg, GraphDev) else float(gg.nw.max())
+        U = max(nw_max, L / f)
+        if restrict is not None and isinstance(restrict, np.ndarray):
+            restrict = eng.to_arena(restrict, gg.n, fill=-1)
+        clus = eng.cluster(
+            gg, U=U, iters=cfg.lp_iters_coarsen, seed=seed, restrict=restrict,
+        )
+        coarse, C = eng.contract(gg, clus)
+        if coarse.n >= cfg.shrink_stall * gg.n or coarse.n < k:
+            return None, None, gg, restrict
+        if restrict is not None:
+            restrict = eng.project_restrict(C, restrict)
+        return coarse, C, gg, restrict
+    U = max(float(gg.nw.max()), L / f)
+    clus = _cluster(gg, U, cfg.lp_iters_coarsen, seed, restrict, cfg, eng)
+    coarse, C = contract(gg, clus)
+    if coarse.n >= cfg.shrink_stall * gg.n or coarse.n < k:
+        return None, None, gg, restrict
+    if restrict is not None:
+        rc = np.zeros(coarse.n, dtype=np.int64)
+        rc[C] = restrict  # consistent: clusters never straddle blocks
+        restrict = rc
+    return coarse, C, gg, restrict

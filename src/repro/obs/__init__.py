@@ -4,8 +4,9 @@ Four pieces, one import surface:
 
 * :class:`MetricsRegistry` / :class:`RegistryBackedStats` — the single
   counter/gauge/histogram store behind every subsystem's stats object;
-* :func:`span` / :class:`Tracer` — nested spans with device-sync close,
-  Chrome-trace export (Perfetto), near-zero overhead when disabled;
+* :func:`span` / :class:`Tracer` — nested spans: non-blocking profiler
+  annotations while ``jax.profiler`` collects, device-sync close and
+  Chrome-trace export (Perfetto) under a Tracer, a shared no-op otherwise;
 * :func:`watchdog` / :class:`CompileWatchdog` — runtime guard promoting
   the "compiles == buckets" test idiom (strict + seal modes);
 * :func:`write_slo` — Prometheus text + JSON snapshot of the serving
@@ -20,7 +21,7 @@ from .memory import (
     DeviceMemoryAccountant, account, accountant, estimate_footprint, pin,
     set_accounting, will_fit,
 )
-from .trace import Span, Tracer, get_tracer, set_tracer, span
+from .trace import Span, Tracer, gc_spans, get_tracer, set_tracer, span
 from .watchdog import (
     KERNEL_FAMILIES, KNOWN_JIT_SITES, CompileRecord, CompileWatchdog,
     WatchdogError, watchdog,
@@ -29,7 +30,7 @@ from .export import slo_snapshot, to_prometheus, write_slo
 
 __all__ = [
     "MetricsRegistry", "RegistryBackedStats",
-    "Span", "Tracer", "get_tracer", "set_tracer", "span",
+    "Span", "Tracer", "gc_spans", "get_tracer", "set_tracer", "span",
     "CompileRecord", "CompileWatchdog", "WatchdogError", "watchdog",
     "KERNEL_FAMILIES", "KNOWN_JIT_SITES",
     "DeviceMemoryAccountant", "accountant", "set_accounting",

@@ -189,6 +189,210 @@ def test_tracer_disabled_instance_returns_noop():
     assert tracer.events == []
 
 
+# ------------------------------------------------- spans on the profiler clock
+
+
+def _profiled(fn, tmp_dir):
+    """Run ``fn`` under ``jax.profiler``; returns its result and the program
+    spans of the trace as ``(name, args)`` (host events with a ``cat``)."""
+    import pathlib
+    import warnings
+
+    import jax
+
+    jax.profiler.start_trace(str(tmp_dir))
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(pathlib.Path(tmp_dir).rglob("*.xplane.pb"))[-1]
+    spans = []
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+            for line in plane.lines:
+                for ev in line.events:
+                    st = dict(ev.stats)
+                    if "cat" in st and not plane.name.startswith("/device:"):
+                        spans.append((ev.name, st))
+    return out, spans
+
+
+# the taxonomy of a partition() call, with the args each span carries
+_PARTITION_SPANS = {
+    "partition": {"n", "m", "k", "seed", "vcycles", "host_reads",
+                  "evo_grow_rounds", "evo_grow_budget"},
+    "vcycle.level": {"level", "n", "m"},
+    "vcycle.pack": {"mode", "n"},
+    "vcycle.sweep": {"mode", "n", "m", "iters", "chunks"},
+    "vcycle.contract": {"n", "m"},
+    "vcycle.evolve": {"device", "n", "m", "islands", "pop", "generations",
+                      "grow_budget"},
+    "vcycle.uncoarsen": {"n", "m"},
+    "vcycle.project": {"n"},
+    "partition.finalize": {"cycle"},
+    "host.read": {"what", "bytes"},
+    "py.gc": {"generation", "collected"},
+}
+
+
+def _small_partition():
+    """An engine-path partition() that coarsens (a few seconds on a CPU)."""
+    from repro.core import PartitionerConfig, partition
+    from repro.graph import rmat
+
+    return partition(rmat(10, 8, seed=2), PartitionerConfig(
+        k=4, seed=1, engine="jnp", coarsest_factor=32))
+
+
+@pytest.fixture(scope="module")
+def partition_runs(tmp_path_factory):
+    """The same partition with tracing off, under the profiler alone (with
+    ``jax.block_until_ready`` recording any call, and collections frequent
+    enough that ``py.gc`` spans appear) and under a Tracer."""
+    import gc
+
+    import jax
+
+    prev = set_tracer(None)
+    try:
+        off = _small_partition()
+        blocks = []
+
+        def _block(x):
+            blocks.append(x)
+            raise AssertionError("sync_on blocked without a Tracer")
+
+        threshold = gc.get_threshold()
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(jax, "block_until_ready", _block)
+            gc.set_threshold(50)
+            try:
+                prof, spans = _profiled(_small_partition,
+                                        tmp_path_factory.mktemp("xplane"))
+            finally:
+                gc.set_threshold(*threshold)
+        set_tracer(Tracer())
+        traced = _small_partition()
+        tracer_events = get_tracer().events
+    finally:
+        set_tracer(prev)
+    return dict(off=off, prof=prof, spans=spans, blocks=blocks,
+                traced=traced, tracer_events=tracer_events)
+
+
+def test_span_under_profiler_is_an_annotation_and_off_again_after(tmp_path):
+    prev = set_tracer(None)
+    try:
+        assert span("a.b") is span("c.d")          # nothing collecting
+
+        def body():
+            with span("outer.op", cat="outer", n=3) as sp:
+                assert sp is not span("x.y") and sp.active
+                sp.sync_on(np.zeros(2))              # never blocks here
+                sp.set(hit=7)
+
+        _, spans = _profiled(body, tmp_path)
+        assert span("a.b") is span("c.d")          # the profiler stopped
+    finally:
+        set_tracer(prev)
+    assert ("outer.op", {"cat": "outer", "n": 3, "hit": 7}) in spans
+
+
+def test_partition_spans_reach_the_profiler_with_their_args(partition_runs):
+    seen = {}
+    for name, args in partition_runs["spans"]:
+        seen.setdefault(name, set()).update(args)
+    for name, keys in _PARTITION_SPANS.items():
+        assert name in seen, name
+        assert keys | {"cat"} <= seen[name], (name, seen[name])
+    part = [a for n, a in partition_runs["spans"] if n == "partition"]
+    stats = partition_runs["prof"].engine_stats
+    assert len(part) == 1
+    assert part[0]["host_reads"] == stats["host_reads"] > 0
+    assert part[0]["evo_grow_rounds"] == stats["evo_grow_rounds"] > 0
+    assert part[0]["evo_grow_budget"] == stats["evo_grow_budget"] > 0
+    assert part[0]["evo_grow_rounds"] <= part[0]["evo_grow_budget"]
+
+
+def test_sync_on_never_blocks_without_a_tracer(partition_runs):
+    assert partition_runs["blocks"] == []
+
+
+def test_partition_labels_bit_identical_with_profiler_and_tracer(partition_runs):
+    off = partition_runs["off"].labels
+    np.testing.assert_array_equal(off, partition_runs["prof"].labels)
+    np.testing.assert_array_equal(off, partition_runs["traced"].labels)
+
+
+def test_host_reads_same_with_tracing_on_and_off(partition_runs):
+    reads = [partition_runs[r].engine_stats["host_reads"]
+             for r in ("off", "prof", "traced")]
+    assert reads[0] > 0 and reads == [reads[0]] * 3
+    rounds = [partition_runs[r].engine_stats["evo_grow_rounds"]
+              for r in ("off", "prof", "traced")]
+    assert rounds[0] == 0 and rounds[1] == rounds[2] > 0   # read only traced
+    names = {e["name"] for e in partition_runs["tracer_events"]}
+    assert set(_PARTITION_SPANS) - {"py.gc"} <= names      # Tracer mode too
+
+
+class _Unreadable:
+    """A device scalar stand-in whose every host conversion fails."""
+
+    def _fail(self, *a, **k):
+        raise AssertionError("the grow-round scalar was read")
+
+    __array__ = __int__ = __float__ = __index__ = __bool__ = _fail
+
+
+def test_grow_round_scalar_is_never_read_with_tracing_off(
+        monkeypatch, partition_runs):
+    from repro.core import evo_device
+
+    seed_step = evo_device.evo_seed_step
+
+    def unreadable_rounds(*a, **k):
+        labs, keys, _ = seed_step(*a, **k)
+        return labs, keys, _Unreadable()
+
+    monkeypatch.setattr(evo_device, "evo_seed_step", unreadable_rounds)
+    prev = set_tracer(None)
+    try:
+        rep = _small_partition()
+    finally:
+        set_tracer(prev)
+    np.testing.assert_array_equal(rep.labels, partition_runs["off"].labels)
+    assert rep.engine_stats["evo_grow_rounds"] == 0
+    assert rep.engine_stats["evo_grow_budget"] > 0
+
+
+@pytest.mark.parametrize("case", ["planted", "mesh"])
+def test_evo_grow_rounds_match_the_numpy_oracle(case):
+    """The device's grow-loop trips are the max, over the population, of
+    the frontier rounds the numpy oracle runs (it exits on the same
+    converged / stalled conditions); unread until asked for."""
+    from repro.core import LPEngine
+    from repro.core.evolutionary import EvoConfig, grow_rounds_bound
+    from repro.core.metrics import lmax
+    from repro.graph import mesh2d, planted_partition
+
+    g = (planted_partition(600, 4, p_in=0.05, p_out=0.004, seed=1)
+         if case == "planted" else mesh2d(24))
+    k = 4
+    cfg = EvoConfig(k=k, Lmax=lmax(g.n, k, 0.03), islands=2,
+                    pop_per_island=2, generations=0, refine_iters=2, seed=9)
+    eng = LPEngine(g, seed=0)
+    lab = eng.evolve_device(g, cfg)
+    assert eng.stats.evo_grow_rounds == 0               # left on the device
+    assert eng.stats.evo_grow_budget == grow_rounds_bound(g.n, k, g.m)
+    oracle_rounds: list = []
+    np.testing.assert_array_equal(
+        np.asarray(lab), eng.evolve_oracle(g, cfg, grow_rounds=oracle_rounds))
+    assert len(oracle_rounds) == 4 and max(oracle_rounds) > 1
+    assert eng.read_grow_rounds() == max(oracle_rounds)
+    assert eng.read_grow_rounds() == max(oracle_rounds)  # read once, kept
+
+
 # ------------------------------------------------------------------ watchdog
 
 

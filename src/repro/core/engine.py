@@ -158,6 +158,8 @@ class EngineStats(RegistryBackedStats):
         "evo_grow_rounds",      # device GA grow-loop trips, summed over seed
                                 # steps; counted only where read (tracing)
         "evo_grow_budget",      # grow_rounds_bound summed over seed steps
+        "evo_table_steps",      # seed + generation steps whose node rounds
+                                # read the neighbour table (no scatter)
     )
     _SET_FIELDS = (
         "buckets",              # distinct (C, N, E, A, W)
@@ -243,6 +245,7 @@ class LPEngine:
         self._ells: Dict[int, _DeviceEll] = {}
         self._cin: Dict[int, tuple] = {}    # padded contraction inputs (GraphNP)
         self._degs: Dict[int, jax.Array] = {}  # (Ab,) f32 degree arrays (evo)
+        self._tables: Dict[int, tuple] = {}  # id(g) -> (g, evo table or None)
         self._indptrs: Dict[int, jax.Array] = {}  # device row ptrs (GraphNP)
         self._repair_E = 0                  # sticky region-pack edge bucket
         self._iota_cache: Optional[jax.Array] = None  # lazy: dist path may never sweep
@@ -549,6 +552,7 @@ class LPEngine:
         self._ells = {k: v for k, v in self._ells.items() if k in keep_ids}
         self._cin = {k: v for k, v in self._cin.items() if k in keep_ids}
         self._degs = {k: v for k, v in self._degs.items() if k in keep_ids}
+        self._tables = {k: v for k, v in self._tables.items() if k in keep_ids}
         self._indptrs = {k: v for k, v in self._indptrs.items() if k in keep_ids}
         self._exact = {k: v for k, v in self._exact.items() if k in keep_ids}
 
@@ -948,8 +952,48 @@ class LPEngine:
         self._degs[id(g)] = arr
         return arr
 
+    def _evo_table(self, g: AnyGraph, Ab: int):
+        """One-row-per-node neighbour table ``(nbr, wt)`` of the GA's node
+        rounds, or None where the graph's shape says the per-arc scatter is
+        cheaper.  ``W`` is the max degree rounded up to 8 and ``Nt`` is ``n``
+        rounded up to the 256-row tile (at most ``Ab``); padding slots point
+        at their own row with weight 0, so every slot indexes one of the
+        first ``Nt`` labels (see ``evo_device._table_conn``).  The table is
+        taken when its ``Nt * W`` slots are at most ``4 * m``: bounded-degree
+        meshes, not graphs whose hubs would pad every row.  Only the O(n)
+        row plan is built on the host, from the indptr the GA's degrees
+        already read; the slots are gathered on device from the arena's CSR
+        arcs."""
+        hit = self._tables.get(id(g))
+        if hit is not None and hit[0] is g:
+            return hit[1]
+        indptr = g._indptr_np() if isinstance(g, GraphDev) else g.indptr
+        deg = np.diff(np.asarray(indptr[: g.n + 1], dtype=np.int64))
+        W = -(-max(int(deg.max(initial=0)), 1) // 8) * 8
+        Nt = min(-(-g.n // 256) * 256, Ab)
+        table = None
+        if Nt * W <= 4 * g.m:
+            _, first, end = plan_ell_rows(indptr, g.n, width=W)
+            ar = self._arena(g)
+            self.stats.gather_builds += 1
+            gkey = ("evo_table", Nt, W, ar.dst.shape[0])
+            if gkey not in self._gather_keys:
+                self._gather_keys.add(gkey)
+                self.stats.gather_compiles += 1
+                _obs_watchdog().note("engine.gather", gkey)
+            nbr, wt = gather_ell_device(
+                jnp.asarray(first[:Nt]), jnp.asarray(end[:Nt]), ar.dst, ar.ew,
+                jnp.int32(g.n), width=W,
+            )
+            rows = jnp.arange(Nt, dtype=jnp.int32)[:, None]
+            table = (jnp.where(nbr == g.n, rows, nbr), wt)
+            self.stats.h2d_bytes += 2 * Nt * 4
+            _mem_account("chunk_packs", *table)
+        self._tables[id(g)] = (g, table)
+        return table
+
     def _weights_exact(self, g: AnyGraph) -> bool:
-        """Whether the GA's scatter sums are exact in any order on ``g``:
+        """Whether the GA's sums are exact in any order on ``g``:
         integral weights, total node weight (block weights) and the largest
         weighted degree (per-node block connections) below 2**24 in f32, and
         an arc total the int32 cut of the fitness key can hold.  Checked on
@@ -1037,7 +1081,9 @@ class LPEngine:
                 )
                 seed_mask[row] = True
         self.stats.h2d_bytes += seed_lab.nbytes + seed_mask.nbytes
-        skey = ("evo_seed", dp.shape, Sb, Ab, Kb, cfg.refine_iters)
+        table = self._evo_table(g, Ab)
+        tshape = None if table is None else table[0].shape
+        skey = ("evo_seed", dp.shape, Sb, Ab, Kb, cfg.refine_iters, tshape)
         self.stats.evo_calls += 1
         if skey not in self.stats.evo_buckets:
             self.stats.evo_buckets.add(skey)
@@ -1055,19 +1101,21 @@ class LPEngine:
             jnp.float32(cfg.Lmax), jnp.int32(seed_eff),
             jnp.int32(I), jnp.int32(P), jnp.int32(n), jnp.int32(k),
             jnp.int32(dp.num_chunks),
-            jnp.int32(budget),
+            jnp.int32(budget), table,
             refine_iters=cfg.refine_iters, Kb=Kb,
         )
         self._grow_rounds.append(rounds)    # read only by read_grow_rounds
+        self.stats.evo_table_steps += int(table is not None)
         _mem_account("evo_population", labs, keys)
         D = jax.device_count()
         if shard and G > 0 and D > 1 and I % D == 0:
             labs, keys = self._evolve_sharded(
                 g, cfg, dp, ar, labs, keys, nw_ab, seed_eff, D,
-                make_generation_sharded,
+                make_generation_sharded, table,
             )
         else:
-            gkey = ("evo_gen", dp.shape, Sb, Ab, Ib, Kb, cfg.refine_iters)
+            gkey = ("evo_gen", dp.shape, Sb, Ab, Ib, Kb, cfg.refine_iters,
+                    tshape)
             for gen in range(G):
                 self.stats.evo_calls += 1
                 if gkey not in self.stats.evo_buckets:
@@ -1081,9 +1129,10 @@ class LPEngine:
                     jnp.float32(cfg.Lmax), jnp.int32(seed_eff),
                     jnp.int32(gen), jnp.int32(0),
                     jnp.int32(I), jnp.int32(P), jnp.int32(n), jnp.int32(k),
-                    jnp.int32(dp.num_chunks),
+                    jnp.int32(dp.num_chunks), table,
                     refine_iters=cfg.refine_iters, Kb=Kb, Ib=Ib,
                 )
+                self.stats.evo_table_steps += int(table is not None)
                 _mem_account("evo_population", labs, keys)
         Sb_cur = labs.shape[0]
         valid = jnp.arange(Sb_cur) < I * P
@@ -1094,7 +1143,7 @@ class LPEngine:
         return labs[jnp.minimum(bidx, Sb_cur - 1)][:n]
 
     def _evolve_sharded(self, g, cfg, dp, ar, labs, keys, nw_ab, seed_eff,
-                        D, make_step):
+                        D, make_step, table):
         """Generation loop over ``shard_map`` island shards (device evo's
         distributed mode); state is resharded (D, Sb_loc, Ab) around the
         single-device seed phase and flattened back for best-selection."""
@@ -1117,7 +1166,8 @@ class LPEngine:
             key_sh[d, :S_loc] = key_h[d * S_loc:(d + 1) * S_loc]
         offs = (np.arange(D, dtype=np.int32) * I_loc)[:, None]
         stat_key = ("evo_gen_sharded", dp.shape, D, Sb_loc, Ab, Ib_loc, Kb,
-                    cfg.refine_iters)
+                    cfg.refine_iters,
+                    None if table is None else table[0].shape)
         # keyed on the step's actual statics (a mesh identity would miss on
         # every call — make_mesh returns a fresh object — and re-jit the
         # shard_map executable once per V-cycle)
@@ -1146,8 +1196,9 @@ class LPEngine:
                 jnp.float32(cfg.Lmax), jnp.int32(seed_eff), jnp.int32(gen),
                 offs_d,
                 jnp.int32(I_loc), jnp.int32(P), jnp.int32(n), jnp.int32(k),
-                jnp.int32(dp.num_chunks),
+                jnp.int32(dp.num_chunks), table,
             )
+            self.stats.evo_table_steps += int(table is not None)
         # flatten back to island-major flat order (gossip already global)
         lab_fh, key_fh = self.host_read((labs_d, keys_d), "population")
         self.stats.d2h_bytes += lab_fh.nbytes + key_fh.nbytes
@@ -1443,6 +1494,7 @@ class LPEngine:
             host_reads=self.stats.host_reads,
             evo_grow_rounds=self.stats.evo_grow_rounds,
             evo_grow_budget=self.stats.evo_grow_budget,
+            evo_table_steps=self.stats.evo_table_steps,
             arena=self.A,
             chunk_bucket=(self.C_bucket, self.N, self.E_floor),
         )

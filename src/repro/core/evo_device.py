@@ -7,7 +7,10 @@ runs as ONE bucketed jitted executable —
 * **batched greedy-growing seeds** — hash-scored degree-biased seed draw,
   degree/diameter-proportional synchronous frontier rounds
   (``evolutionary.grow_rounds_bound``, traced; converged/stalled frontiers
-  exit early), round-robin leftovers;
+  exit early), round-robin leftovers.  On bounded-degree graphs the
+  frontier and gain rounds read node-block connectivity from a
+  one-row-per-node neighbour table (``LPEngine._evo_table``) instead of
+  per-arc scatter-adds;
 * **batched LP refinement** — a ``vmap`` population axis over the engine's
   cached ``_lp_sweep`` chunk pack (the graph uploads once per run, not once
   per individual), followed by synchronous gain (FM-lite) and balance-repair
@@ -105,13 +108,36 @@ def _evaluate(lab, src, dst, ew, nw, k, Kb, Lmax):
     return cut + jnp.where(feas, 0, INFEAS_PENALTY)
 
 
-def _greedy_one(s_idx, src, dst, ew, nw, deg_f, n, k, Kb, Lmax, seed, rounds):
+def _table_conn(table, lab, Kb):
+    """``(Nt, Kb)`` node-block connectivity from the one-row-per-node
+    neighbour table ``(nbr, wt)``: ``conn[v, b]`` sums the weights of ``v``'s
+    slots whose neighbour holds label ``b``.  A gather of the label row and
+    a compare-sum over the ``W`` slots, one reduce fusion: no scatter.
+    Unassigned neighbours (-1) match no block; padding slots weigh 0.
+
+    Every slot indexes a node below ``Nt``, so the gather reads the fresh
+    ``Nt``-label slice, which the TPU compiler keeps in on-chip memory: on
+    a v5e that gather costs a quarter of one from the loop-carried arena
+    in HBM."""
+    nbr, wt = table
+    t = lab[: nbr.shape[0]][nbr]
+    kio = jnp.arange(Kb, dtype=jnp.int32)
+    return jnp.sum(
+        jnp.where(t[:, :, None] == kio, wt[:, :, None], 0.0), axis=1
+    )
+
+
+def _greedy_one(s_idx, src, dst, ew, nw, deg_f, n, k, Kb, Lmax, seed, rounds,
+                table=None):
     """Batched greedy growing, one individual (oracle: ``_greedy_grow_np``).
 
     ``rounds`` is the traced degree/diameter-proportional budget
     (``evolutionary.grow_rounds_bound``) — one executable still serves
-    every coarsest graph in the bucket.  Returns the labels and the rounds
-    this individual ran."""
+    every coarsest graph in the bucket.  With a neighbour ``table`` each
+    round reads its connectivity from the table and does its dense work on
+    the table's ``Nt`` rows; without, from per-arc scatter-adds over the
+    ``Ab`` arena.  Both give the same labels: every sum is of integers
+    below 2**24.  Returns the labels and the rounds this individual ran."""
     Ab = nw.shape[0]
     iota = jnp.arange(Ab, dtype=jnp.int32)
     kio = jnp.arange(Kb, dtype=jnp.int32)
@@ -120,27 +146,35 @@ def _greedy_one(s_idx, src, dst, ew, nw, deg_f, n, k, Kb, Lmax, seed, rounds):
     order = sort_by_keys(-skey)
     rank = jnp.zeros((Ab,), jnp.int32).at[order].set(iota)
     lab0 = jnp.where((rank < k) & (iota < n), rank, jnp.int32(-1))
+    R = Ab if table is None else table[0].shape[0]
+    io, nwr = iota[:R], nw[:R]
 
     def grow_round(r, lab):
-        tgt = lab[dst]
-        mask = tgt >= 0
-        conn = jnp.zeros((Ab, Kb), jnp.float32).at[
-            src, jnp.where(mask, tgt, 0)
-        ].add(jnp.where(mask, ew, 0.0))
-        asg = lab >= 0
-        bw = jnp.zeros((Kb,), jnp.float32).at[jnp.where(asg, lab, 0)].add(
-            jnp.where(asg, nw, 0.0)
-        )
+        lr = lab[:R]
+        if table is None:
+            tgt = lab[dst]
+            mask = tgt >= 0
+            conn = jnp.zeros((Ab, Kb), jnp.float32).at[
+                src, jnp.where(mask, tgt, 0)
+            ].add(jnp.where(mask, ew, 0.0))
+            asg = lab >= 0
+            bw = jnp.zeros((Kb,), jnp.float32).at[jnp.where(asg, lab, 0)].add(
+                jnp.where(asg, nw, 0.0)
+            )
+        else:
+            conn = _table_conn(table, lab, Kb)
+            bw = jnp.sum(jnp.where(lr[:, None] == kio, nwr[:, None], 0.0),
+                         axis=0)
         bwx = jnp.where(kio < k, bw, jnp.inf)
         base_r = _hash_u32(_hash_base(seed, r, TAG_GROW), s_idx, jnp.int32(0))
-        jit = _hash_jitter(base_r, iota[:, None], kio[None, :])
-        fits = bwx[None, :] + nw[:, None] <= Lmax
+        jit = _hash_jitter(base_r, io[:, None], kio[None, :])
+        fits = bwx[None, :] + nwr[:, None] <= Lmax
         elig = (conn > 0) & fits
         score = jnp.where(elig, conn + jit, _NEG)
         b = jnp.argmax(score, axis=1).astype(jnp.int32)
-        has = jnp.take_along_axis(score, b[:, None], 1)[:, 0] > _NEG / 2
-        unas = (lab < 0) & (iota < n)
-        return jnp.where(unas & has, b, lab)
+        has = jnp.max(score, axis=1) > _NEG / 2
+        unas = (lr < 0) & (io < n)
+        return lab.at[:R].set(jnp.where(unas & has, b, lr))
 
     # while_loop instead of a fixed fori: once every node is assigned — or a
     # round assigns nothing (a stalled frontier can never recover, since
@@ -172,23 +206,32 @@ def _greedy_one(s_idx, src, dst, ew, nw, deg_f, n, k, Kb, Lmax, seed, rounds):
     return jnp.where(iota < n, lab, k).astype(jnp.int32), trips
 
 
-def _gain_round(src, dst, ew, nw, lab, n, k, Kb, Lmax, base_score, base_gate):
-    """Synchronous best-gain round (oracle: ``repro.core.fm.gain_round_np``)."""
+def _gain_round(src, dst, ew, nw, lab, n, k, Kb, Lmax, base_score, base_gate,
+                table=None):
+    """Synchronous best-gain round (oracle: ``repro.core.fm.gain_round_np``);
+    connectivity from the neighbour ``table`` where there is one, as in
+    :func:`_greedy_one`."""
     Ab = lab.shape[0]
-    iota = jnp.arange(Ab, dtype=jnp.int32)
     kio = jnp.arange(Kb, dtype=jnp.int32)
-    conn = jnp.zeros((Ab, Kb), jnp.float32).at[src, lab[dst]].add(ew)
-    own = jnp.take_along_axis(conn, jnp.minimum(lab, Kb - 1)[:, None], 1)[:, 0]
+    if table is None:
+        R = Ab
+        conn = jnp.zeros((Ab, Kb), jnp.float32).at[src, lab[dst]].add(ew)
+    else:
+        R = table[0].shape[0]
+        conn = _table_conn(table, lab, Kb)
+    iota = jnp.arange(R, dtype=jnp.int32)
+    lr = lab[:R]
+    own = jnp.sum(jnp.where(kio == lr[:, None], conn, 0.0), axis=1)
     _, bwx = _bw_dev(lab, nw, k, Kb)
     jit = _hash_jitter(base_score, iota[:, None], kio[None, :])
-    fits = bwx[None, :] + nw[:, None] <= Lmax
-    elig = fits & (kio[None, :] != lab[:, None]) & (conn > own[:, None])
+    fits = bwx[None, :] + nw[:R, None] <= Lmax
+    elig = fits & (kio[None, :] != lr[:, None]) & (conn > own[:, None])
     score = jnp.where(elig, conn + jit, _NEG)
     b = jnp.argmax(score, axis=1).astype(jnp.int32)
-    has = jnp.take_along_axis(score, b[:, None], 1)[:, 0] > _NEG / 2
+    has = jnp.max(score, axis=1) > _NEG / 2
     u = _hash_unit(base_gate, iota, jnp.int32(0))
     move = has & (u < 0.5) & (iota < n)
-    return jnp.where(move, b, lab)
+    return lab.at[:R].set(jnp.where(move, b, lr))
 
 
 def _repair_rounds(src, dst, ew, nw, lab, ctx, phase, n, k, Kb, Lmax, seed):
@@ -287,13 +330,14 @@ def _combine_init(src, dst, ew, nw, lab1, lab2, lab_better, i_ctx, gen, n, k,
 
 
 def _refine_batch(pack, labs, ctxs, phase, src, dst, ew, nw, n, k, Kb, Lmax,
-                  num_chunks, seed, refine_iters):
+                  num_chunks, seed, refine_iters, table=None):
     """Batched refine: vmapped ``_lp_sweep`` + gain rounds + repair rounds.
 
     ``labs`` is ``(B, Ab)``; ``ctxs`` the per-row hash contexts (flat
     individual index in the seed phase, global island id in generations);
     ``phase`` 0 for seeding, ``gen + 1`` for generations (oracle twin:
-    ``_refine_np``)."""
+    ``_refine_np``); ``table`` the neighbour table of the gain rounds, or
+    None."""
     nodes, node_valid, edge_dst, edge_w, edge_src_slot, edge_valid = pack
     kio = jnp.arange(Kb, dtype=jnp.int32)
     sw = (
@@ -317,22 +361,27 @@ def _refine_batch(pack, labs, ctxs, phase, src, dst, ew, nw, n, k, Kb, Lmax,
         )
         return out
 
-    labs = jax.vmap(sweep_one)(labs, ws, sw)
-    for r in range(GAIN_ROUNDS):
-        base_s = _hash_u32(_hash_base(seed, phase, TAG_GAIN), ctxs, jnp.int32(r))
-        base_g = _hash_u32(
-            _hash_base(seed, phase, TAG_GAIN_GATE), ctxs, jnp.int32(r)
-        )
-        labs = jax.vmap(
-            lambda lab, bs, bg: _gain_round(
-                src, dst, ew, nw, lab, n, k, Kb, Lmax, bs, bg
+    with jax.named_scope("evo.sweep"):
+        labs = jax.vmap(sweep_one)(labs, ws, sw)
+    with jax.named_scope("evo.gain"):
+        for r in range(GAIN_ROUNDS):
+            base_s = _hash_u32(
+                _hash_base(seed, phase, TAG_GAIN), ctxs, jnp.int32(r)
             )
-        )(labs, base_s, base_g)
-    labs = jax.vmap(
-        lambda lab, ctx: _repair_rounds(
-            src, dst, ew, nw, lab, ctx, phase, n, k, Kb, Lmax, seed
-        )
-    )(labs, ctxs)
+            base_g = _hash_u32(
+                _hash_base(seed, phase, TAG_GAIN_GATE), ctxs, jnp.int32(r)
+            )
+            labs = jax.vmap(
+                lambda lab, bs, bg: _gain_round(
+                    src, dst, ew, nw, lab, n, k, Kb, Lmax, bs, bg, table
+                )
+            )(labs, base_s, base_g)
+    with jax.named_scope("evo.repair"):
+        labs = jax.vmap(
+            lambda lab, ctx: _repair_rounds(
+                src, dst, ew, nw, lab, ctx, phase, n, k, Kb, Lmax, seed
+            )
+        )(labs, ctxs)
     return labs
 
 
@@ -368,6 +417,7 @@ def evo_seed_step(
     Lmax,               # scalar f32
     seed,               # scalar int32
     I, P, n, k, num_chunks, grow_rounds,   # traced scalars
+    table=None,         # (nbr (Nt, W) int32, wt (Nt, W) f32) or None
     *,
     refine_iters: int,
     Kb: int,
@@ -376,7 +426,10 @@ def evo_seed_step(
     unseeded rows (``grow_rounds`` frontier-round budget — traced, computed
     by ``evolutionary.grow_rounds_bound``), verbatim seed rows (the
     V-cycle's projected solution), batched refine, int32 fitness keys.  ONE
-    executable per ``(pack bucket, Sb, Ab, Kb)`` shape.
+    executable per ``(pack bucket, Sb, Ab, Kb, table shape)``; the grow and
+    gain rounds read the neighbour ``table`` where one is given.  Its
+    phases are named scopes ``evo.grow``, ``evo.sweep``, ``evo.gain``,
+    ``evo.repair`` and ``evo.evaluate`` in the device trace.
 
     Returns ``(labels, keys, grow_trips)``: the last is the number of trips
     the device made through the grow loop, the max over all ``Sb`` rows of
@@ -386,19 +439,22 @@ def evo_seed_step(
     iota_s = jnp.arange(Sb, dtype=jnp.int32)
     valid_s = iota_s < I * P
     pack = (nodes, node_valid, edge_dst, edge_w, edge_src_slot, edge_valid)
-    grown, trips = jax.vmap(
-        lambda s: _greedy_one(
-            s, src, dst, ew, nw, deg_f, n, k, Kb, Lmax, seed, grow_rounds
-        )
-    )(iota_s)
+    with jax.named_scope("evo.grow"):
+        grown, trips = jax.vmap(
+            lambda s: _greedy_one(
+                s, src, dst, ew, nw, deg_f, n, k, Kb, Lmax, seed, grow_rounds,
+                table,
+            )
+        )(iota_s)
     refined = _refine_batch(
         pack, grown, iota_s, jnp.int32(0), src, dst, ew, nw, n, k, Kb, Lmax,
-        num_chunks, seed, refine_iters,
+        num_chunks, seed, refine_iters, table,
     )
     labs = jnp.where(seed_mask[:, None], seed_labels, refined)
-    keys = jax.vmap(
-        lambda lab: _evaluate(lab, src, dst, ew, nw, k, Kb, Lmax)
-    )(labs)
+    with jax.named_scope("evo.evaluate"):
+        keys = jax.vmap(
+            lambda lab: _evaluate(lab, src, dst, ew, nw, k, Kb, Lmax)
+        )(labs)
     keys = jnp.where(valid_s, keys, jnp.int32(_IMAX))
     return labs, keys, jnp.max(trips)
 
@@ -406,7 +462,7 @@ def evo_seed_step(
 def _generation_core(
     pack, labs, keys, src, dst, ew, nw, Lmax, seed, gen, island_offset,
     I, P, n, k, num_chunks, Kb: int, Ib: int, refine_iters: int,
-    axis_name=None,
+    axis_name=None, table=None,
 ):
     """One generation: selection, combine/mutate, batched refine, elitism,
     replacement, gossip.  Shared by the single-device jit and the
@@ -454,7 +510,7 @@ def _generation_core(
 
     children = _refine_batch(
         pack, init, i_ctx, gen + 1, src, dst, ew, nw, n, k, Kb, Lmax,
-        num_chunks, seed, refine_iters,
+        num_chunks, seed, refine_iters, table,
     )
     ckeys = jax.vmap(
         lambda lab: _evaluate(lab, src, dst, ew, nw, k, Kb, Lmax)
@@ -506,16 +562,18 @@ def evo_generation_step(
     src, dst, ew, nw,
     Lmax, seed, gen, island_offset,
     I, P, n, k, num_chunks,
+    table=None,
     *,
     refine_iters: int,
     Kb: int,
     Ib: int,
 ):
-    """One generation as ONE executable per (pack bucket, Sb, Ab, Ib, Kb)."""
+    """One generation as ONE executable per (pack bucket, Sb, Ab, Ib, Kb,
+    table shape)."""
     pack = (nodes, node_valid, edge_dst, edge_w, edge_src_slot, edge_valid)
     return _generation_core(
         pack, labs, keys, src, dst, ew, nw, Lmax, seed, gen, island_offset,
-        I, P, n, k, num_chunks, Kb, Ib, refine_iters,
+        I, P, n, k, num_chunks, Kb, Ib, refine_iters, table=table,
     )
 
 
@@ -529,12 +587,12 @@ def make_generation_sharded(mesh, refine_iters: int, Kb: int, Ib: int):
     def step(pack_and_state):
         (nodes, node_valid, edge_dst, edge_w, edge_src_slot, edge_valid,
          labs, keys, src, dst, ew, nw, Lmax, seed, gen, island_offset,
-         I_loc, P, n, k, num_chunks) = pack_and_state
+         I_loc, P, n, k, num_chunks, table) = pack_and_state
         pack = (nodes, node_valid, edge_dst, edge_w, edge_src_slot, edge_valid)
         labs, keys = _generation_core(
             pack, labs[0], keys[0], src, dst, ew, nw, Lmax, seed, gen,
             island_offset[0, 0], I_loc, P, n, k, num_chunks,
-            Kb, Ib, refine_iters, axis_name="island",
+            Kb, Ib, refine_iters, axis_name="island", table=table,
         )
         return labs[None], keys[None]
 
@@ -545,6 +603,7 @@ def make_generation_sharded(mesh, refine_iters: int, Kb: int, Ib: int):
         rep, rep, rep, rep,                             # arc arrays + nw
         rep, rep, rep, PS("island"),                    # Lmax, seed, gen, off
         rep, rep, rep, rep, rep,                        # I_loc, P, n, k, chunks
+        rep,                                            # neighbour table
     )
     sharded = jax.shard_map(
         lambda *a: step(a), mesh=mesh,

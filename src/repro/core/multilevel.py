@@ -293,7 +293,8 @@ def _uncoarsen_level(gg_f, C, lab, lab_dev, k, L, cfg, rng, eng):
 
 
 # engine counters a traced ``partition`` span closes with
-_SPAN_COUNTERS = ("host_reads", "evo_grow_rounds", "evo_grow_budget")
+_SPAN_COUNTERS = ("host_reads", "evo_grow_rounds", "evo_grow_budget",
+                  "evo_table_steps")
 
 
 def partition(g, cfg: PartitionerConfig) -> PartitionReport:
@@ -307,7 +308,8 @@ def partition(g, cfg: PartitionerConfig) -> PartitionReport:
 
     The whole call is the ``partition`` span (args n, m, k, seed, vcycles);
     when tracing, it closes with the engine's ``host_reads``,
-    ``evo_grow_rounds`` and ``evo_grow_budget`` as metadata.
+    ``evo_grow_rounds``, ``evo_grow_budget`` and ``evo_table_steps`` as
+    metadata.
     """
     with _obs_span(
         "partition", cat="partition", n=int(g.n), m=int(g.m), k=int(cfg.k),

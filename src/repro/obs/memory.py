@@ -528,6 +528,7 @@ KNOWN_ALLOC_SITES: Dict[str, str] = {
     "core/engine.py::_contract_inputs": "base_csr",
     "core/engine.py::_deg_f": "evo_population",
     "core/engine.py::_ell": "chunk_packs",
+    "core/engine.py::_evo_table": "chunk_packs",
     "core/engine.py::_evolve_sharded": "evo_population",
     "core/engine.py::_indptr_dev": "base_csr",
     "core/engine.py::_iota": "label_arenas",

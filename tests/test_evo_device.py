@@ -19,7 +19,10 @@ from repro.core import LPEngine, PartitionerConfig, initial_partition, partition
 from repro.core.evolutionary import EvoConfig, evolve_batched_numpy
 from repro.core.metrics import cut_np, lmax
 from _subproc import run_with_devices
-from repro.graph import GraphDev, barabasi_albert, mesh2d, planted_partition
+from repro.graph import (
+    GraphDev, GraphNP, barabasi_albert, from_edges, mesh2d, planted_partition,
+    rgg, ring,
+)
 
 
 def _cfg(k, L, I, P, G, seed, seeds=()):
@@ -31,17 +34,20 @@ def _cfg(k, L, I, P, G, seed, seeds=()):
 @pytest.mark.parametrize(
     "case",
     [
-        # (graph builder, k, islands, pop, generations)
+        # (graph builder, k, islands, pop, generations, neighbour table?)
         (lambda: planted_partition(700, 6, p_in=0.05, p_out=0.004, seed=1),
-         2, 2, 2, 3),
-        (lambda: barabasi_albert(500, 4, seed=2), 4, 4, 3, 2),
+         2, 2, 2, 3, True),
+        (lambda: barabasi_albert(500, 4, seed=2), 4, 4, 3, 2, False),  # hubs
         (lambda: planted_partition(300, 4, p_in=0.06, p_out=0.01, seed=3),
-         3, 1, 2, 2),
-        (lambda: barabasi_albert(64, 3, seed=4), 2, 2, 1, 3),  # mutate-only
+         3, 1, 2, 2, True),
+        (lambda: barabasi_albert(64, 3, seed=4), 2, 2, 1, 3, False),  # mutate
+        # meshes at the benchmark's k = 32 (Kb = 64): the table path
+        (lambda: mesh2d(40), 32, 2, 2, 2, True),
+        (lambda: rgg(11, seed=1), 32, 2, 2, 0, True),         # "fast" preset
     ],
 )
 def test_device_matches_oracle_bit_for_bit(case):
-    gbuild, k, I, P, G = case
+    gbuild, k, I, P, G, table = case
     g = gbuild()
     L = lmax(g.n, k, 0.03)
     eng = LPEngine(g, seed=0)
@@ -50,6 +56,103 @@ def test_device_matches_oracle_bit_for_bit(case):
     lab_dev = np.asarray(eng.evolve_device(g, cfg))
     lab_ora = eng.evolve_oracle(g, cfg)
     np.testing.assert_array_equal(lab_dev, lab_ora)
+    # the seed step and every generation step took the path the shape says
+    assert eng.stats.evo_table_steps == (1 + G if table else 0)
+
+
+def _table_gate(g):
+    """The gate's rule from the graph's shape alone: ``Nt * W <= 4 * m``."""
+    W = -(-max(int(g.degrees().max()), 1) // 8) * 8
+    Nt = min(-(-g.n // 256) * 256, 1 << g.n.bit_length())
+    return Nt * W <= 4 * g.m
+
+
+def _with_hub(g, hub_deg):
+    """``g`` plus arcs from node 0 to ``hub_deg`` far nodes."""
+    src = g.arc_sources()
+    keep = src < g.indices
+    far = np.arange(g.n // 2, g.n // 2 + hub_deg)
+    return from_edges(
+        g.n, np.concatenate([src[keep], np.zeros(hub_deg, np.int64)]),
+        np.concatenate([g.indices[keep], far]),
+    )
+
+
+def test_neighbour_table_gate_follows_shape_alone():
+    """The GA takes the neighbour table exactly when ``Nt * W <= 4 * m``:
+    a 256-ring sits on the bound (2,048 slots, 512 arcs) and takes it; one
+    more node (a second 256-row tile) or a hub (W = 16) tips it over.  The
+    weights never enter: tripled arc weights decide the same."""
+    r = ring(256)
+    heavy = GraphNP(indptr=r.indptr, indices=r.indices, ew=r.ew * 3,
+                    nw=r.nw * 2)
+    cases = [(r, True), (heavy, True), (ring(257), False),
+             (_with_hub(r, 9), False), (mesh2d(40), True),
+             (barabasi_albert(500, 4, seed=2), False)]
+    for g, want in cases:
+        assert _table_gate(g) == want
+        eng = LPEngine(g, seed=0)
+        table = eng._evo_table(g, 1 << g.n.bit_length())
+        assert (table is not None) == want
+        if want:
+            nbr, wt = table
+            W = nbr.shape[1]
+            assert W % 8 == 0 and W >= g.degrees().max()
+            assert nbr.shape[0] * W <= 4 * g.m
+
+
+def _king(side):
+    """``side x side`` grid with all eight neighbours: interior degree 8."""
+    i, j = np.divmod(np.arange(side * side), side)
+    us, vs = [], []
+    for di, dj in ((0, 1), (1, 0), (1, 1), (1, -1)):
+        ok = (i + di < side) & (j + dj >= 0) & (j + dj < side)
+        us.append(np.flatnonzero(ok))
+        vs.append((i[ok] + di) * side + j[ok] + dj)
+    return from_edges(side * side, np.concatenate(us), np.concatenate(vs))
+
+
+def test_grow_and_gain_rounds_on_a_full_table_row():
+    """A node of degree exactly ``W`` fills its table row with no padding
+    slot: the grow loop and the gain round read the same connectivity from
+    the table as from the per-arc scatter (labels and trips equal), and the
+    whole GA stays bit-identical to the numpy oracle."""
+    from repro.core.evo_device import _gain_round, _greedy_one
+    from repro.core.evolutionary import grow_rounds_bound
+
+    g = _king(20)
+    assert g.degrees().max() == 8
+    k, Kb = 8, 16
+    L = lmax(g.n, k, 0.03)
+    eng = LPEngine(g, seed=0)
+    Ab = 1 << g.n.bit_length()
+    nbr, wt = eng._evo_table(g, Ab)
+    assert nbr.shape[1] == 8
+    full = np.flatnonzero(g.degrees() == 8)
+    assert full.size and np.all(np.asarray(wt)[full] > 0)   # no pad slot
+    rows = np.sort(np.asarray(nbr)[full], axis=1)
+    adj = np.sort(g.indices[g.indptr[full][:, None] + np.arange(8)], axis=1)
+    np.testing.assert_array_equal(rows, adj)                # the whole row
+    ar = eng._arena(g)
+    nw = ar.nw_arena[:Ab]
+    deg = eng._deg_f(g, Ab)
+    args = (ar.src, ar.dst, ar.ew, nw, deg, jnp.int32(g.n), jnp.int32(k),
+            Kb, jnp.float32(L), jnp.int32(5),
+            jnp.int32(grow_rounds_bound(g.n, k, g.m)))
+    for s in range(3):
+        lab_s, trips_s = _greedy_one(jnp.int32(s), *args)
+        lab_t, trips_t = _greedy_one(jnp.int32(s), *args, (nbr, wt))
+        np.testing.assert_array_equal(np.asarray(lab_t), np.asarray(lab_s))
+        assert int(trips_t) == int(trips_s) > 0
+        base = jnp.uint32(0x9E3779B1 * (s + 1) & 0xFFFFFFFF)
+        gain = [_gain_round(ar.src, ar.dst, ar.ew, nw, lab_s, jnp.int32(g.n),
+                            jnp.int32(k), Kb, jnp.float32(L), base, base + 1,
+                            t) for t in (None, (nbr, wt))]
+        np.testing.assert_array_equal(np.asarray(gain[1]), np.asarray(gain[0]))
+    cfg = _cfg(k, L, 2, 2, 1, seed=3)
+    np.testing.assert_array_equal(np.asarray(eng.evolve_device(g, cfg)),
+                                  eng.evolve_oracle(g, cfg))
+    assert eng.stats.evo_table_steps == 2
 
 
 def test_seeded_device_evo_parity_and_never_worse_than_seed():
@@ -101,15 +204,10 @@ def test_graphdev_coarsest_consumed_without_host_materialization():
     np.testing.assert_array_equal(np.asarray(lab_dev), lab_ora)
 
 
-def test_evo_compile_count_bounded_by_buckets():
-    """Compile-count regression: across a multi-V-cycle partition run the
-    batched evo compiles once per (phase, shape-bucket) — never per call.
-
-    The engine's ``evo_compiles == evo_bucket_count`` is definitional (both
-    derive from the same key set), so the real assertion is against the jit
-    caches of the evo entry points themselves: their growth across the run
-    must not exceed the reported bucket count (a per-call shape drift would
-    blow straight past it)."""
+def _evo_compiles_within_buckets(g):
+    """Partition ``g`` over two V-cycles with two generations; the evo entry
+    points' jit caches may grow by at most the engine's bucket count.
+    Returns the engine stats."""
     from repro.core.evo_device import evo_generation_step, evo_seed_step
 
     def _jit_entries():
@@ -120,7 +218,6 @@ def test_evo_compile_count_bounded_by_buckets():
         except Exception:
             return None
 
-    g = barabasi_albert(4096, 5, seed=1)
     cfg = PartitionerConfig(k=2, preset="fast", coarsest_factor=50, seed=0,
                             engine="jnp", generations=2, islands=2,
                             pop_per_island=2)
@@ -135,6 +232,27 @@ def test_evo_compile_count_bounded_by_buckets():
     after = _jit_entries()
     if before is not None and after is not None:
         assert after - before <= st["evo_bucket_count"]
+    return st
+
+
+def test_evo_compile_count_bounded_by_buckets():
+    """Compile-count regression: across a multi-V-cycle partition run the
+    batched evo compiles once per (phase, shape-bucket) — never per call.
+
+    The engine's ``evo_compiles == evo_bucket_count`` is definitional (both
+    derive from the same key set), so the real assertion is against the jit
+    caches of the evo entry points themselves: their growth across the run
+    must not exceed the reported bucket count (a per-call shape drift would
+    blow straight past it)."""
+    _evo_compiles_within_buckets(barabasi_albert(4096, 5, seed=1))
+
+
+def test_evo_compile_count_bounded_on_the_table_path():
+    """The same bound where the GA reads the neighbour table: the table's
+    shape is part of the evo bucket key, so it adds no unaccounted
+    executable."""
+    st = _evo_compiles_within_buckets(mesh2d(64))
+    assert st["evo_table_steps"] > 0
 
 
 def test_partition_evo_engine_host_fallback_matches_legacy():

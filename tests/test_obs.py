@@ -221,7 +221,7 @@ def _profiled(fn, tmp_dir):
 # the taxonomy of a partition() call, with the args each span carries
 _PARTITION_SPANS = {
     "partition": {"n", "m", "k", "seed", "vcycles", "host_reads",
-                  "evo_grow_rounds", "evo_grow_budget"},
+                  "evo_grow_rounds", "evo_grow_budget", "evo_table_steps"},
     "vcycle.level": {"level", "n", "m"},
     "vcycle.pack": {"mode", "n"},
     "vcycle.sweep": {"mode", "n", "m", "iters", "chunks"},
@@ -313,6 +313,7 @@ def test_partition_spans_reach_the_profiler_with_their_args(partition_runs):
     assert part[0]["evo_grow_rounds"] == stats["evo_grow_rounds"] > 0
     assert part[0]["evo_grow_budget"] == stats["evo_grow_budget"] > 0
     assert part[0]["evo_grow_rounds"] <= part[0]["evo_grow_budget"]
+    assert part[0]["evo_table_steps"] == stats["evo_table_steps"]
 
 
 def test_sync_on_never_blocks_without_a_tracer(partition_runs):

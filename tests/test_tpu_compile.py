@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.contraction import contract_device, packed_key_wbits
-from repro.core.evo_device import evo_generation_step
+from repro.core.evo_device import evo_generation_step, evo_seed_step
 from repro.core.label_propagation import _lp_sweep
 from repro.dynamic.repair import expand_region_device, gain_round_device
 from repro.dynamic.store import merge_overlay_device
@@ -41,6 +41,11 @@ COARSE_AB = pow2(COARSE_N + 1)
 COARSE_MB = arc_bucket(M_ARCS // 8)
 ELL_ROWS = pow2(N_NODES + M_ARCS // 128)
 OVERLAY = 4096                        # one churn batch: 2 x 1,024 edges
+# the benchmark's rgg-mesh (scale 15, k=32): the GA runs on the whole mesh
+# and reads its neighbour table (n rounded to 256 rows, max degree 27 -> 32)
+MESH_N, MESH_M = 1 << 15, 322_340
+MESH_AB = pow2(MESH_N + 1)
+_mesh_n, _mesh_e = chunk_geometry(MESH_N, MESH_M, 64)
 
 
 @pytest.fixture(scope="module")
@@ -159,6 +164,21 @@ def _evo(shape):
     return evo_generation_step.lower(*args, refine_iters=6, Kb=Kb, Ib=Ib)
 
 
+def _evo_seed_table(shape):
+    Sb, Kb, W = 4, pow2(K + 1), 32
+    C, Nc, Ec = 64, pow2(_mesh_n), -(-_mesh_e // 512) * 512
+    args = (
+        shape((C, Nc), I32), shape((C, Nc), BOOL), shape((C, Ec), I32),
+        shape((C, Ec), F32), shape((C, Ec), I32), shape((C, Ec), BOOL),
+        shape((Sb, MESH_AB), I32), shape((Sb,), BOOL),
+        shape((MESH_M,), I32), shape((MESH_M,), I32), shape((MESH_M,), F32),
+        shape((MESH_AB,), F32), shape((MESH_AB,), F32),
+        _scalar(shape, F32), _scalar(shape, I32),
+    ) + tuple(_scalar(shape, I32) for _ in range(6))
+    table = (shape((MESH_N, W), I32), shape((MESH_N, W), F32))
+    return evo_seed_step.lower(*args, table, refine_iters=6, Kb=Kb)
+
+
 def _lp_score(shape, width):
     return lp_score_rows.lower(
         shape((ELL_ROWS, width), I32), shape((ELL_ROWS, width), F32),
@@ -174,6 +194,7 @@ KERNELS = {
     "expand_region_device": _expand,
     "gain_round_device": _gain,
     "evo_generation_step": _evo,
+    "evo_seed_step_table": _evo_seed_table,
     "lp_score_rows_w8": functools.partial(_lp_score, width=8),
     "lp_score_rows_w64": functools.partial(_lp_score, width=64),
     "lp_score_rows_w128": functools.partial(_lp_score, width=128),
